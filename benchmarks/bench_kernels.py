@@ -1,9 +1,10 @@
 """Kernel benchmark: per-kernel correctness (vs oracle) + analytic TPU-v5e
 roofline terms for the production shapes each kernel serves.
 
-No TPU in this container — correctness runs in interpret mode; the roofline
-terms are derived from the kernels' exact FLOP/byte counts and the v5e
-constants (these are the numbers the block sizes were chosen against)."""
+Off the TPU the kernels run in interpret mode, so the correctness checks
+hold on any backend; the roofline terms are derived from the kernels' exact
+FLOP/byte counts and the v5e constants (these are the numbers the block
+sizes were chosen against), not timed."""
 from __future__ import annotations
 
 import numpy as np
@@ -78,7 +79,7 @@ def main(quick: bool = False):
         ("flash_attention", (128 * 128 + 2 * 128 * 128 + 128 * 128) * 4),
         ("decode_attention", (8 * 128 + 2 * 512 * 128 + 8 * 128) * 4),
         ("mamba_scan", (128 * 512 * 3 + 512 * 16) * 4),
-        ("top2gap", (8 * 512 + 3 * 8) * 4),
+        ("top2gap", (8 * 2048 + 3 * 8 * 128) * 4),
     ]:
         res.add(f"{name}_vmem_kb", round(ws / 1024, 1),
                 fits_vmem=bool(ws < hw.VMEM_BYTES))

@@ -16,7 +16,7 @@ and lets any executor run on any physics:
   today's server physics. Plugged into the simulator it runs REAL model
   compute under a virtual clock.
 * ``CostModelBackend`` — the analytic TPU-v5e roofline for the assigned big
-  architectures (no accelerator in this container), replayed like profiles.
+  architectures, replayed like profiles (analytic, not measured).
 
 Profile production is unified the same way: ``profile_backend(backend, ...)``
 is the one entry point that turns any backend into the ``ModelProfile``
@@ -369,8 +369,11 @@ class EngineBackend(ExecutionBackend):
         return self.profiles[model].runtime(batch_size)
 
     def execute(self, model: str, sids: Sequence[int],
-                tokens: Optional[Sequence[np.ndarray]] = None
-                ) -> BatchExecution:
+                tokens: Optional[Sequence[np.ndarray]] = None,
+                device=None) -> BatchExecution:
+        """As the protocol's ``execute``; ``device`` (a JAX device) runs the
+        batch there instead of on the default device, through the engine's
+        ``infer(tokens, device=...)``."""
         if tokens is None:
             if self._tokens is None:
                 raise RuntimeError(
@@ -380,10 +383,20 @@ class EngineBackend(ExecutionBackend):
             batch = self._tokens[[s % pool_n for s in sids]]
         else:
             batch = np.stack([np.asarray(t) for t in tokens])
+        engine = self.engines[model]
+        placed = {} if device is None else {"device": device}
         t0 = time.perf_counter()
-        scores = self.engines[model].infer(batch)
+        scores = engine.infer(batch, **placed)
         elapsed = time.perf_counter() - t0
-        certs = np.asarray(self.estimator(scores), np.float64)
+        # the estimator sees power-of-two row counts only (zero rows pad,
+        # sliced off): a JAX estimator then compiles once per size class on
+        # each device, not once per batch size in the serving path
+        n = len(scores)
+        rows = 1 << (n - 1).bit_length()
+        padded = np.pad(scores, ((0, rows - n), (0, 0)))
+        import jax
+        with jax.default_device(device):        # beside its batch
+            certs = np.asarray(self.estimator(padded), np.float64)[:n]
         preds = scores.argmax(-1)
         correct = None
         if tokens is None and self._labels is not None:
@@ -450,8 +463,8 @@ class EngineBackend(ExecutionBackend):
 # ---------------------------------------------------------------------------
 
 class CostModelBackend(ReplayBackend):
-    """The assigned big architectures cannot run on this container, so their
-    physics come from the analytic TPU-v5e roofline
+    """Physics for the assigned big architectures from the analytic
+    TPU-v5e roofline
     (``repro.profiling.cost_model.analytic_runtime``) with synthetic or
     measured validation behaviour replayed per sample — a ReplayBackend
     whose profiles are derived, not measured.

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On a TPU backend the kernels lower natively; everywhere else (this CPU dev
-container) they run in ``interpret=True`` mode — same kernel body, Python
-semantics — which is how the tests validate them against ``ref.py``.
+On a TPU backend the kernels lower natively; on any other backend they run
+in ``interpret=True`` mode — same kernel body, Python semantics — which is
+how the tests validate them against ``ref.py``.
 """
 from __future__ import annotations
 
@@ -24,7 +24,7 @@ def _interpret() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_v"))
-def top2gap(scores: jax.Array, block_b: int = 8, block_v: int = 512
+def top2gap(scores: jax.Array, block_b: int = 8, block_v: int = 2048
             ) -> Tuple[jax.Array, jax.Array]:
     """(gap, argmax) over the last axis. scores (B, V)."""
     return top2gap_pallas(scores, block_b=block_b, block_v=block_v,

@@ -7,7 +7,7 @@ blocks HBM->VMEM and keeps running (top1, top2, argmax) accumulators in VMEM
 scratch, fusing what would otherwise be two full top-k sorts.
 
 Grid: (B/BB, V/BV), vocab innermost so the scratch carries across blocks.
-Block sizes default to (8, 512) — sublane x lane aligned (8, 128)-multiples.
+Block sizes default to (8, 2048) — sublane x lane aligned (8, 128)-multiples.
 """
 from __future__ import annotations
 
@@ -20,6 +20,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# per-row results live in one lane-dense (rows, 128) tile, every lane holding
+# the same value: Mosaic refuses rank-1 blocks that are not multiples of 128
+_LANES = 128
 
 
 def _top2gap_kernel(x_ref, gap_ref, idx_ref, m1, m2, ai, *, n_vblocks: int,
@@ -34,27 +37,25 @@ def _top2gap_kernel(x_ref, gap_ref, idx_ref, m1, m2, ai, *, n_vblocks: int,
 
     x = x_ref[...].astype(jnp.float32)  # (BB, BV)
     bb, bv = x.shape
-    # mask out-of-range vocab positions (padding of the last block)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bb, bv), 1) + j * block_v
-    x = jnp.where(col < vocab, x, NEG_INF)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bb, bv), 1)
+    # mask positions past the vocab (the tail of the last, partial block)
+    x = jnp.where(lane + j * block_v < vocab, x, NEG_INF)
 
-    loc1 = jnp.max(x, axis=-1)                          # (BB,)
-    loc_arg = jnp.argmax(x, axis=-1).astype(jnp.int32)  # (BB,)
-    masked = jnp.where(
-        jax.lax.broadcasted_iota(jnp.int32, (bb, bv), 1)
-        == loc_arg[:, None], NEG_INF, x)
-    loc2 = jnp.max(masked, axis=-1)                     # (BB,)
+    loc1 = jnp.max(x, axis=-1, keepdims=True)                   # (BB, 1)
+    # lowest lane holding the block maximum: ties go to the lowest index
+    loc_arg = jnp.min(jnp.where(x == loc1, lane, bv), axis=-1,
+                      keepdims=True)                            # (BB, 1)
+    loc2 = jnp.max(jnp.where(lane == loc_arg, NEG_INF, x), axis=-1,
+                   keepdims=True)                               # (BB, 1)
 
-    cur1, cur2, cur_ai = m1[...], m2[...], ai[...]
+    cur1, cur2, cur_ai = m1[...], m2[...], ai[...]              # (BB, 128)
+    # strict: an equal maximum in a later block keeps the earlier index
     better = loc1 > cur1
-    new1 = jnp.where(better, loc1, cur1)
     # runner-up: best of {loser of (cur1, loc1), cur2, loc2}
     loser = jnp.where(better, cur1, loc1)
-    new2 = jnp.maximum(loser, jnp.maximum(cur2, loc2))
-    new_ai = jnp.where(better, loc_arg + j * block_v, cur_ai)
-    m1[...] = new1
-    m2[...] = new2
-    ai[...] = new_ai
+    m2[...] = jnp.maximum(loser, jnp.maximum(cur2, loc2))
+    m1[...] = jnp.where(better, loc1, cur1)
+    ai[...] = jnp.where(better, loc_arg + j * block_v, cur_ai)
 
     @pl.when(j == n_vblocks - 1)
     def _out():
@@ -62,35 +63,31 @@ def _top2gap_kernel(x_ref, gap_ref, idx_ref, m1, m2, ai, *, n_vblocks: int,
         idx_ref[...] = ai[...]
 
 
-def top2gap_pallas(scores: jax.Array, block_b: int = 8, block_v: int = 512,
+def top2gap_pallas(scores: jax.Array, block_b: int = 8, block_v: int = 2048,
                    interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """scores (B, V) -> (gap (B,) f32, argmax (B,) i32)."""
-    b, v = scores.shape
-    pad_b = (-b) % block_b
-    pad_v = (-v) % block_v
-    if pad_b or pad_v:
-        scores = jnp.pad(scores, ((0, pad_b), (0, pad_v)),
-                         constant_values=NEG_INF)
-    bp, vp = scores.shape
-    n_vblocks = vp // block_v
+    """scores (B, V) -> (gap (B,) f32, argmax (B,) i32).
 
+    The grid covers (B, V) in (block_b, block_v) tiles without padding the
+    input: the partial tiles at the edges are masked inside the kernel
+    (vocab) or dropped on write-back (rows)."""
+    b, v = scores.shape
+    n_vblocks = pl.cdiv(v, block_v)
     kernel = functools.partial(_top2gap_kernel, n_vblocks=n_vblocks,
                                block_v=block_v, vocab=v)
+    row_block = pl.BlockSpec((block_b, _LANES), lambda i, j: (i, 0))
     gap, idx = pl.pallas_call(
         kernel,
-        grid=(bp // block_b, n_vblocks),
-        in_specs=[pl.BlockSpec((block_b, block_v),
-                               lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((block_b,), lambda i, j: (i,)),
-                   pl.BlockSpec((block_b,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((bp,), jnp.float32),
-                   jax.ShapeDtypeStruct((bp,), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((block_b,), jnp.float32),
-                        pltpu.VMEM((block_b,), jnp.float32),
-                        pltpu.VMEM((block_b,), jnp.int32)],
+        grid=(pl.cdiv(b, block_b), n_vblocks),
+        in_specs=[pl.BlockSpec((block_b, block_v), lambda i, j: (i, j))],
+        out_specs=[row_block, row_block],
+        out_shape=[jax.ShapeDtypeStruct((b, _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((b, _LANES), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((block_b, _LANES), jnp.float32),
+                        pltpu.VMEM((block_b, _LANES), jnp.float32),
+                        pltpu.VMEM((block_b, _LANES), jnp.int32)],
         interpret=interpret,
     )(scores)
-    return gap[:b], idx[:b]
+    return gap[:, 0], idx[:, 0]
 
 
 def argmax_gap(scores: jax.Array) -> Tuple[jax.Array, jax.Array]:

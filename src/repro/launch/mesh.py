@@ -20,13 +20,22 @@ from repro.distributed.context import DistContext
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]
               ) -> jax.sharding.Mesh:
     """Arbitrary mesh (tests / smoke runs on few devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]
+               ) -> jax.sharding.Mesh:
+    # jax.make_mesh defaults to Explicit axes, under which
+    # with_sharding_constraint refuses every axis; the model code places
+    # its activations with constraints, so all axes are Auto
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def context_for_mesh(mesh: Optional[jax.sharding.Mesh],

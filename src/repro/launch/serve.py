@@ -7,7 +7,7 @@ Two workloads, three execution backends (DESIGN.md §9):
 * ``--workload qwen``  — the assigned-architecture family (qwen2-0.5b ->
   qwen3-32b, per DESIGN.md §6) behind a ``CostModelBackend`` (analytic
   TPU-v5e roofline + synthetic validation behaviour), served on the
-  discrete-event simulator (this container has no TPU for the big models).
+  discrete-event simulator: its profiles are analytic, not measured.
 * ``--stress-replay``  — the threaded WALL-CLOCK runtime over a
   ``ReplayBackend``: no model compute, so the scheduler/queue machinery can
   be stressed at QPS far beyond what real inference allows.
@@ -188,6 +188,8 @@ def main() -> None:
                     help="multi-tenant mode (DESIGN.md §11): comma-"
                          "separated name:slokind:value:qps_max[:weight]")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.workload == "tiny":
         backend = tiny_backend(args.artifact)
@@ -256,14 +258,22 @@ def main() -> None:
         if telem is not None:
             dump_metrics(telem, args.metrics_out)
     elif args.real and args.workload == "tiny":
+        import jax
         from repro.serving.runtime import CascadeServer, Request
         from repro.serving.tinymodels import synthetic_classification_data
-        for e in backend.engines.values():
-            e.warmup(32)
+        # plan device d runs on JAX device d where there are enough of them
+        devices = jax.devices()[:plan.num_devices] \
+            if len(jax.devices()) >= plan.num_devices else None
+        print(f"plan devices -> "
+              f"{[str(d) for d in devices] if devices else 'default device'}")
+        for d in devices or [None]:
+            for e in backend.engines.values():
+                e.warmup(32, device=d)
         n_req = int(trace.sum()) + 8
         toks, labels, _ = synthetic_classification_data(n_req, seed=7)
         reqs = [Request(rid=i, tokens=toks[i]) for i in range(n_req)]
-        server = CascadeServer(plan, backend=backend, telemetry=telem)
+        server = CascadeServer(plan, backend=backend, telemetry=telem,
+                               devices=devices)
         done = server.run_trace(reqs, trace)
         lats = np.array([r.latency for r in done])
         acc = np.mean([int(r.pred == labels[r.rid]) for r in done])

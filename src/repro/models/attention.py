@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed import compat
 from repro.distributed.sharding import constrain
 from repro.models.common import ArrayFactory, Params, apply_rope
 
@@ -353,7 +352,7 @@ def decode_attention_sharded(p: Params, cfg: ModelConfig, x: jax.Array,
         return out.reshape(b_loc, 1, h, hd).astype(q_loc.dtype), kc, vc
 
     dp = (batch_axes if len(batch_axes) != 1 else batch_axes[0]) or None
-    out, k_cache, v_cache = compat.shard_map(
+    out, k_cache, v_cache = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(dp, None, None, None),   # q (full heads, replicated)
                   P(dp, None, None, None),   # k_new
@@ -364,7 +363,7 @@ def decode_attention_sharded(p: Params, cfg: ModelConfig, x: jax.Array,
         out_specs=(P(dp, None, None, None),
                    P(dp, model_axis, None, None),
                    P(dp, model_axis, None, None)),
-        axis_names=set(batch_axes) | {model_axis},
+        axis_names=set(batch_axes) | {model_axis}, check_vma=False,
     )(q, k_new, v_new, cache["k"], cache["v"], cache_index)
     out = out.reshape(b, 1, h * hd) @ p["wo"]
     return out, {"k": k_cache, "v": v_cache}

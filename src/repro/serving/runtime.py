@@ -116,7 +116,54 @@ class _TenantReplicaQueue(_ReplicaQueue):
             return batch
 
 
-class CascadeServer:
+class _ServingThreads:
+    """The thread set of a threaded driver. The first exception any of its
+    threads raises stops them all, and ``stop()`` re-raises it in the
+    caller: a dead consumer fails the run instead of silently shortening
+    it."""
+
+    def __init__(self):
+        self._stop = threading.Event()
+        self._threads: List[threading.Thread] = []
+        self._failure: Optional[BaseException] = None
+        self._failure_lock = threading.Lock()
+
+    def _start_threads(self, targets: Sequence[Tuple[Callable, tuple]]
+                       ) -> None:
+        self._stop.clear()
+        self._failure = None
+        self._threads = [threading.Thread(target=self._guarded,
+                                          args=(fn,) + args, daemon=True)
+                         for fn, args in targets]
+        for t in self._threads:
+            t.start()
+
+    def _guarded(self, fn: Callable, *args) -> None:
+        try:
+            fn(*args)
+        except Exception as e:
+            with self._failure_lock:
+                if self._failure is None:
+                    self._failure = e
+            self._stop.set()
+
+    def stop(self) -> None:
+        """Stop every thread; re-raise the first exception one raised."""
+        self._stop.set()
+        for t in self._threads:
+            t.join(timeout=2.0)
+        with self._failure_lock:
+            err, self._failure = self._failure, None
+        if err is not None:
+            raise err
+
+    def _wait(self, seconds: float) -> bool:
+        """Sleep up to ``seconds``; True (at once) if the threads stopped,
+        which mid-run means one of them failed."""
+        return self._stop.wait(max(seconds, 0.0))
+
+
+class CascadeServer(_ServingThreads):
     """Gear-plan-driven online server, backend-agnostic.
 
     ``backend`` supplies the execution physics; by default the given
@@ -126,6 +173,11 @@ class CascadeServer:
     is how the baseline policies of ``repro.serving.baselines`` execute on
     the real runtime, via the same ``GearSelector`` protocol the simulator
     uses.
+
+    ``devices`` binds the plan's devices to JAX devices: plan device ``d``
+    runs its batches on ``devices[d]`` (the backend's ``execute`` then
+    takes a ``device``, as ``EngineBackend``'s does). Without it every
+    batch runs on JAX's default device.
     """
 
     def __init__(self, plan: GearPlan,
@@ -138,7 +190,13 @@ class CascadeServer:
                  decision_trace: Optional[DecisionTrace] = None,
                  seed: int = 0, lifecycle=None,
                  backend: Optional[ExecutionBackend] = None,
-                 telemetry=None):
+                 telemetry=None, devices: Optional[Sequence] = None):
+        super().__init__()
+        if devices is not None and len(devices) != plan.num_devices:
+            raise ValueError(
+                f"devices binds {len(devices)} JAX devices to a plan of "
+                f"{plan.num_devices} devices")
+        self.devices = None if devices is None else list(devices)
         # (active plan, current gear index, plan epoch) as ONE tuple: a
         # hot-swap (or a gear switch) replaces the reference in a single
         # assignment, so a concurrent submit/_poll_replica thread always
@@ -174,11 +232,9 @@ class CascadeServer:
             _ReplicaQueue() for _ in plan.replicas]
         self._arr_count = 0
         self._count_lock = threading.Lock()
-        self._stop = threading.Event()
         self.completed: List[Request] = []
         self._done_lock = threading.Lock()
         self.gear_switches: List = []
-        self._threads: List[threading.Thread] = []
 
     @property
     def plan(self) -> GearPlan:
@@ -269,18 +325,19 @@ class CascadeServer:
 
     def _run_batch(self, model: str, batch: List,
                    now: Optional[float] = None,
-                   on_enqueue: Optional[Callable[[int, float], None]] = None
-                   ) -> None:
+                   on_enqueue: Optional[Callable[[int, float], None]] = None,
+                   device=None) -> None:
         """Execute one batch through the backend, then resolve or cascade
         each sample per the core's continuation decision. ``on_enqueue(ridx,
         t)`` is notified of each cascade push (run_virtual uses it to
         schedule polls; the threaded consumers poll continuously and pass
-        nothing)."""
+        nothing). ``device`` is the bound JAX device, if any."""
         reqs = [r for r, _ in batch]
+        placed = {} if device is None else {"device": device}
         # the ONLY execution call: jitted engines, validation replay, or
         # any other backend — the driver never special-cases the source
         ex = self.backend.execute(model, [r.rid for r in reqs],
-                                  tokens=[r.tokens for r in reqs])
+                                  tokens=[r.tokens for r in reqs], **placed)
         certs, preds = ex.certs, ex.preds
         t = time.monotonic() if now is None else now
         for i, req in enumerate(reqs):
@@ -321,13 +378,15 @@ class CascadeServer:
 
     def _consumer_loop(self, device: int):
         my_reps = self.core.reps_on_dev.get(device, [])
+        bound = self.devices[device] if self.devices is not None else None
         while not self._stop.is_set():
             ran = False
             now = time.monotonic()
             for ridx in my_reps:
                 batch = self._poll_replica(ridx, now)
                 if batch:
-                    self._run_batch(self.plan.replicas[ridx].model, batch)
+                    self._run_batch(self.plan.replicas[ridx].model, batch,
+                                    device=bound)
                     ran = True
             if not ran:
                 time.sleep(0.0005)
@@ -340,36 +399,26 @@ class CascadeServer:
         if self.lifecycle is not None and \
                 self.lifecycle.replanner is not None:
             self.lifecycle.replanner.threaded = True
-        self._stop.clear()
-        self._threads = [threading.Thread(target=self._producer_loop,
-                                          daemon=True)]
-        for d in range(self.plan.num_devices):
-            self._threads.append(threading.Thread(
-                target=self._consumer_loop, args=(d,), daemon=True))
-        for t in self._threads:
-            t.start()
-
-    def stop(self) -> None:
-        self._stop.set()
-        for t in self._threads:
-            t.join(timeout=2.0)
+        self._start_threads(
+            [(self._producer_loop, ())]
+            + [(self._consumer_loop, (d,))
+               for d in range(self.plan.num_devices)])
 
     def run_trace(self, requests: Sequence[Request],
                   qps_per_sec: np.ndarray, drain: float = 2.0
                   ) -> List[Request]:
         """Open-loop replay: issue requests per the trace regardless of
-        completion (paper §6.2)."""
+        completion (paper §6.2). Raises what a serving thread raised."""
         from repro.core.simulator import trace_to_arrivals
         arrivals = trace_to_arrivals(qps_per_sec)
         assert len(requests) >= len(arrivals)
         self.start()
         t0 = time.monotonic()
         for i, at in enumerate(arrivals):
-            delay = t0 + at - time.monotonic()
-            if delay > 0:
-                time.sleep(delay)
+            if self._wait(t0 + at - time.monotonic()):
+                break
             self.submit(requests[i])
-        time.sleep(drain)
+        self._wait(drain)
         self.stop()
         return list(self.completed)
 
@@ -601,7 +650,7 @@ class CascadeServer:
 # Multi-tenant frontend (core/tenancy.py)
 # ---------------------------------------------------------------------------
 
-class MultiTenantServer:
+class MultiTenantServer(_ServingThreads):
     """Several tenants' gear ladders served over ONE shared fleet.
 
     The tenant extension of ``CascadeServer``: per-tenant
@@ -630,6 +679,7 @@ class MultiTenantServer:
                  backend: Optional[ExecutionBackend] = None,
                  route_pools: Optional[Dict[str, RoutePool]] = None,
                  telemetry=None):
+        super().__init__()
         self.mt_plan = mt_plan
         self.names: List[str] = list(mt_plan.names)
         self._tidx = {n: i for i, n in enumerate(self.names)}
@@ -671,7 +721,6 @@ class MultiTenantServer:
             _TenantReplicaQueue(len(self.names)) for _ in self.replicas]
         self._arr_counts = [0] * len(self.names)
         self._count_lock = threading.Lock()
-        self._stop = threading.Event()
         self.completed: Dict[str, List[Request]] = {n: [] for n in
                                                     self.names}
         self.shed_counts: Dict[str, int] = {n: 0 for n in self.names}
@@ -679,7 +728,6 @@ class MultiTenantServer:
         self._done_lock = threading.Lock()
         self.gear_switches: Dict[str, List] = {n: [] for n in self.names}
         self.plan_swaps: Dict[str, List] = {n: [] for n in self.names}
-        self._threads: List[threading.Thread] = []
 
     # --------------------------------------------------- decision steps
     def submit(self, req: Request, now: Optional[float] = None) -> int:
@@ -855,36 +903,27 @@ class MultiTenantServer:
         for lc in self.lifecycles:
             if lc is not None and lc.replanner is not None:
                 lc.replanner.threaded = True
-        self._stop.clear()
-        self._threads = [threading.Thread(target=self._producer_loop,
-                                          daemon=True)]
-        for d in range(self.mt_plan.num_devices):
-            self._threads.append(threading.Thread(
-                target=self._consumer_loop, args=(d,), daemon=True))
-        for t in self._threads:
-            t.start()
-
-    def stop(self) -> None:
-        self._stop.set()
-        for t in self._threads:
-            t.join(timeout=2.0)
+        self._start_threads(
+            [(self._producer_loop, ())]
+            + [(self._consumer_loop, (d,))
+               for d in range(self.mt_plan.num_devices)])
 
     def run_trace(self, requests: Dict[str, Sequence[Request]],
                   traces: Dict[str, np.ndarray], drain: float = 2.0
                   ) -> Dict[str, List[Request]]:
-        """Wall-clock open-loop replay of superposed tenant traces."""
+        """Wall-clock open-loop replay of superposed tenant traces. Raises
+        what a serving thread raised."""
         from repro.core.tenancy import merge_tenant_arrivals
         times, tidx, lidx = merge_tenant_arrivals(traces, self.names)
         self.start()
         t0 = time.monotonic()
         for k in range(len(times)):
-            delay = t0 + times[k] - time.monotonic()
-            if delay > 0:
-                time.sleep(delay)
+            if self._wait(t0 + times[k] - time.monotonic()):
+                break
             req = requests[self.names[int(tidx[k])]][int(lidx[k])]
             req.tenant = self.names[int(tidx[k])]
             self.submit(req)
-        time.sleep(drain)
+        self._wait(drain)
         self.stop()
         return {n: list(v) for n, v in self.completed.items()}
 

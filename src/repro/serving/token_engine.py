@@ -148,12 +148,18 @@ class SlotEngine:
         self.stats = SlotEngineStats()
         self._vocab = cfg.vocab_size
         # --- reference executables (PR-7 loop, parity baseline) ---------
+        # each jitted entry point is a named function: compile logs and
+        # profiler traces report it by that name
+        def reference_decode(p, t, c, i):
+            return model_lib.decode_step(p, cfg, t, c, i)
+
+        def reference_prefill(p, t):
+            return model_lib.prefill(p, cfg, {"tokens": t},
+                                     cache_len=max_len)
+
         # one decode executable, static shape (n_slots, 1) + (n_slots,)
-        self._decode = jax.jit(
-            lambda p, t, c, i: model_lib.decode_step(p, cfg, t, c, i))
-        self._prefill = jax.jit(
-            lambda p, t: model_lib.prefill(p, cfg, {"tokens": t},
-                                           cache_len=max_len))
+        self._decode = jax.jit(reference_decode)
+        self._prefill = jax.jit(reference_prefill)
         # --- fused-loop state (device-resident, DESIGN.md §14) ----------
         self.dev_pos = jnp.zeros((n_slots,), jnp.int32)
         self.dev_tok = jnp.zeros((n_slots,), jnp.int32)
@@ -165,19 +171,19 @@ class SlotEngine:
                                          max_len)
         self.batch_buckets = _pow2_buckets(1, n_slots)
         if model_lib.bucketed_prefill_supported(cfg):
-            self._bucketed = jax.jit(
-                lambda p, t, l: self._bucketed_body(p, t, l))
+            def bucketed_prefill(params, tokens, true_lens):
+                """Batched padded prefill + fused greedy/top-2-gap
+                reduction: the host receives (B,) tokens + (B,) gaps,
+                never (B, V) logits."""
+                from repro.kernels.top2gap import argmax_gap
+                logits, cache = model_lib.prefill_bucketed(
+                    params, cfg, tokens, true_lens, cache_len=max_len)
+                tok, gap = argmax_gap(logits)
+                return tok, gap, cache
+
+            self._bucketed = jax.jit(bucketed_prefill)
         else:
             self._bucketed = None
-
-    def _bucketed_body(self, params, tokens, true_lens):
-        """Batched padded prefill + fused greedy/top-2-gap reduction: the
-        host receives (B,) tokens + (B,) gaps, never (B, V) logits."""
-        from repro.kernels.top2gap import argmax_gap
-        logits, cache = model_lib.prefill_bucketed(
-            params, self.cfg, tokens, true_lens, cache_len=self.max_len)
-        tok, gap = argmax_gap(logits)
-        return tok, gap, cache
 
     @property
     def n_active(self) -> int:
@@ -374,8 +380,8 @@ class SlotEngine:
         if fn is None:
             cfg = self.cfg
 
-            def run(params, tokens, cache, positions, active, fold_state,
-                    k: int):
+            def fused_decode(params, tokens, cache, positions, active,
+                             fold_state, k: int):
                 return model_lib.decode_fused_steps(
                     params, cfg, tokens, cache, positions, active,
                     fold_state, k=k, beta=beta, mode=mode)
@@ -383,10 +389,20 @@ class SlotEngine:
             # the KV cache (and the small device-resident carries) are
             # donated: the executable writes the new cache into the old
             # buffers instead of double-buffering HBM
-            fn = jax.jit(run, static_argnames=("k",),
+            fn = jax.jit(fused_decode, static_argnames=("k",),
                          donate_argnums=(1, 2, 3, 5))
             self._fused_fns[key] = fn
         return fn
+
+    def fused_step_text(self, k: int = 1, mode: str = "ewma",
+                        beta: float = 0.35) -> str:
+        """The compiled text of the fused decode step at this engine's
+        shapes, which shows the kernels the step holds (a Pallas kernel
+        appears as a ``tpu_custom_call`` on the TPU). Compiles afresh; the
+        executable the loop dispatches is not touched."""
+        return self._get_fused(mode, beta).lower(
+            self.params, self.dev_tok, self.cache, self.dev_pos,
+            self.dev_active, self._fold, k=k).compile().as_text()
 
     def decode_fused(self, k: int = 1, mode: str = "ewma",
                      beta: float = 0.35

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed import compat
 from repro.distributed.context import DistContext, get_context, use_context
 from repro.models import model as model_lib
 from repro.training.optimizer import AdamWConfig, adamw_update
@@ -44,7 +43,7 @@ class TrainStepConfig:
 
 def _compressed_pod_allreduce_leaf(g: jax.Array, axis: str) -> jax.Array:
     """Mean over the pod axis with int8 on the wire (manual-axis code)."""
-    npods = compat.axis_size(axis)
+    npods = jax.lax.axis_size(axis)
     gf = g.astype(jnp.float32)
     scale = jnp.max(jnp.abs(gf)) / 127.0 + 1e-12
     q = jnp.clip(jnp.round(gf / scale), -127, 127).astype(jnp.int8)
@@ -67,8 +66,8 @@ def compressed_pod_allreduce(grads: Pytree, mesh: jax.sharding.Mesh,
 
     flat, treedef = jax.tree.flatten(grads)
     specs = tuple(P() for _ in flat)  # manual over pod only; auto elsewhere
-    out = compat.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
-                           axis_names={pod_axis})(*flat)
+    out = jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
+                        axis_names={pod_axis}, check_vma=False)(*flat)
     return jax.tree.unflatten(treedef, list(out))
 
 
@@ -131,17 +130,6 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
         use_compress = (ts_cfg.compress_pod_grads and ctx is not None
                         and ctx.mesh is not None
                         and "pod" in ctx.mesh.axis_names)
-        if use_compress and not compat.supports_partial_manual():
-            # the pod-manual region needs 'pod' manual while data/model stay
-            # automatic (inner sharding constraints mention them); 0.4.x
-            # shard_map cannot express that, so ship uncompressed grads.
-            import warnings
-            warnings.warn(
-                "compress_pod_grads needs partial-manual shard_map "
-                f"(jax >= 0.5; running {jax.__version__}) — falling back "
-                "to the uncompressed bf16/f32 pod all-reduce",
-                RuntimeWarning, stacklevel=2)
-            use_compress = False
         if use_compress:
             # per-pod grads: shard_map manual over 'pod'; XLA (auto axes)
             # still reduces over the in-pod data axis on ICI. Inside the
@@ -157,7 +145,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
                 return loss, metrics, grads
 
             flat_params, ptree = jax.tree.flatten(params)
-            loss, metrics, grads = compat.shard_map(
+            loss, metrics, grads = jax.shard_map(
                 local_grads, mesh=ctx.mesh,
                 in_specs=(jax.tree.unflatten(ptree,
                                              [P()] * len(flat_params)),
@@ -166,12 +154,12 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
                 out_specs=(P(), jax.tree.map(lambda _: P(), {
                     "ce": 0, "aux_loss": 0}),
                     jax.tree.unflatten(ptree, [P()] * len(flat_params))),
-                axis_names={"pod"})(params, batch)
+                axis_names={"pod"}, check_vma=False)(params, batch)
             grads = compressed_pod_allreduce(grads, ctx.mesh)
-            loss = compat.shard_map(
+            loss = jax.shard_map(
                 lambda l: jax.lax.pmean(l, "pod"), mesh=ctx.mesh,
                 in_specs=P(), out_specs=P(),
-                axis_names={"pod"})(loss)
+                axis_names={"pod"}, check_vma=False)(loss)
         else:
             loss, metrics, grads = grads_of(params, batch)
 

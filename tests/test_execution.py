@@ -97,6 +97,25 @@ def test_engine_backend_matches_engine_plus_estimator():
     assert ex.elapsed is not None and ex.elapsed >= 0.0
 
 
+def test_engine_backend_estimator_sees_pow2_rows():
+    """The estimator runs on the batch padded to a power-of-two row count,
+    so a jitted estimator compiles once per size class, not once per batch
+    size; the real rows' certainties are unchanged."""
+    seen = []
+
+    def est(s):
+        seen.append(s.shape[0])
+        return s[:, 0] - s[:, 1]
+
+    b = EngineBackend({"m": _RowEngine()}, estimator=est)
+    for n in (1, 3, 5, 8):
+        ex = b.execute("m", list(range(n)),
+                       tokens=[np.array([i, 0]) for i in range(n)])
+        assert list(ex.certs) == [i + 1.0 for i in range(n)]
+        assert len(ex.preds) == n
+    assert seen == [1, 4, 8, 8]
+
+
 def test_engine_backend_token_and_label_pools():
     """With sid-indexed pools the backend executes from sample ids alone
     (what lets the DES drive real models) and reports correctness."""
@@ -349,3 +368,75 @@ def test_run_virtual_defaults_to_backend_runtime(bert_like_profiles):
     explicit = run(batch_runtime=lambda m, b: profiles[m].runtime(b))
     assert len(implicit) == len(explicit) > 0
     assert [r.t_done for r in implicit] == [r.t_done for r in explicit]
+
+
+class _FailingReplay(ReplayBackend):
+    """Replay physics whose third batch raises, as a crashing model would."""
+
+    def __init__(self, profiles):
+        super().__init__(profiles)
+        self.calls = 0
+
+    def execute(self, model, sids, tokens=None):
+        self.calls += 1
+        if self.calls == 3:
+            raise FloatingPointError("batch 3 blew up")
+        return super().execute(model, sids, tokens)
+
+
+def test_threaded_server_raises_a_consumer_failure(bert_like_profiles):
+    """A consumer thread that raises fails ``run_trace`` with its exception
+    (instead of dying silently and returning a short result), and cuts the
+    trace and the drain short."""
+    import time as _time
+    from repro.serving.runtime import CascadeServer, Request
+    profiles = bert_like_profiles
+    reps = [Replica("tiny", 0, profiles["tiny"].runtime_per_sample(1.0))]
+    g = make_gear(Cascade(("tiny",), ()), reps)
+    plan = GearPlan(qps_max=500.0, gears=[g], replicas=reps, num_devices=1,
+                    slo=SLO(kind="latency", latency_p95=1.0))
+    backend = _FailingReplay(profiles)
+    server = CascadeServer(plan, backend=backend)
+    trace = np.full(4, 50.0)
+    reqs = [Request(rid=i, tokens=np.zeros(1, np.int32))
+            for i in range(int(trace.sum()) + 4)]
+    t0 = _time.monotonic()
+    with pytest.raises(FloatingPointError, match="batch 3"):
+        server.run_trace(reqs, trace, drain=30.0)
+    assert _time.monotonic() - t0 < 10.0
+    assert not any(t.is_alive() for t in server._threads)
+    server.stop()                    # the failure is reported once
+
+
+def test_threaded_server_runs_batches_on_bound_devices(bert_like_profiles):
+    """``devices=`` binds plan device d to a JAX device: each consumer runs
+    its batches there, as the devices of the engines' outputs show."""
+    import time as _time
+    import jax
+    import jax.numpy as jnp
+    from repro.serving.engine import InferenceEngine
+    from repro.serving.runtime import CascadeServer, Request
+    profiles = bert_like_profiles
+    reps = [Replica("tiny", d, profiles["tiny"].runtime_per_sample(1.0))
+            for d in range(2)]
+    g = make_gear(Cascade(("tiny",), ()), reps)
+    plan = GearPlan(qps_max=500.0, gears=[g], replicas=reps, num_devices=2,
+                    slo=SLO(kind="latency", latency_p95=1.0))
+    eng = InferenceEngine(
+        "tiny", lambda p, t: jnp.stack([t[:, 0] * p, -t[:, 0] * p], -1)
+        .astype(jnp.float32), jnp.float32(1.0), buckets=(1, 2, 4, 8))
+    dev = jax.devices()[0]
+    with pytest.raises(ValueError):
+        CascadeServer(plan, engines={"tiny": eng}, devices=[dev])
+    server = CascadeServer(plan, engines={"tiny": eng}, devices=[dev, dev])
+    server.start()
+    for i in range(16):
+        server.submit(Request(rid=i, tokens=np.full(4, i + 1, np.int32)))
+    deadline = _time.monotonic() + 10.0
+    while len(server.completed) < 16 and _time.monotonic() < deadline:
+        _time.sleep(0.01)
+    server.stop()
+    assert len(server.completed) == 16
+    assert all(r.pred == 0 for r in server.completed)
+    assert set(eng.batches_by_device) == {dev}
+    assert eng.params_on(dev).devices() == {dev}

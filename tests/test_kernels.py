@@ -41,7 +41,7 @@ def test_top2gap_ties_and_blocks():
     x[0, 700] = 7.0  # exact tie in another vocab block
     x[1, 1000] = 3.0
     x[1, 1] = 2.5
-    gap, idx = top2gap_pallas(jnp.asarray(x), interpret=True)
+    gap, idx = top2gap_pallas(jnp.asarray(x), block_v=512, interpret=True)
     assert abs(float(gap[0])) < 1e-6
     assert abs(float(gap[1]) - 0.5) < 1e-6
     assert int(idx[1]) == 1000
@@ -105,11 +105,6 @@ def test_decode_attention_sweep(b, h, hkv, c, d, vl):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:3]) < (0, 4, 37),
-    reason="ragged (B,) valid_len in interpret-mode pallas needs the "
-           f"per-row BlockSpec scalar path (jax {jax.__version__}; "
-           "needs >= 0.4.37)")
 @pytest.mark.parametrize("vl", [[100, 7, 256], [1, 64, 33]])
 def test_decode_attention_ragged_batch(vl):
     """Per-row (B,) valid_len — the continuous-batching cache layout:

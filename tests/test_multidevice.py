@@ -8,11 +8,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-# These paths target the jax >= 0.5 shard_map surface; on 0.4.x the
-# repro.distributed.compat shim translates them (fully-manual fallback;
-# compress_pod_grads degrades to the uncompressed pod all-reduce with a
-# RuntimeWarning), so the integration runs on either version.
-
 _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -113,8 +108,7 @@ print("SEQ_PARALLEL_OK", err5)
 
 @pytest.mark.slow
 def test_multidevice_subprocess():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900,
                          cwd=os.path.dirname(os.path.dirname(
@@ -141,8 +135,7 @@ def test_dryrun_cell_subprocess():
         "row2 = run_cell('olmo-1b', 'decode_32k', 'multi')\n"
         "assert row2['status'] == 'ok', row2.get('error')\n"
         "print('DRYRUN_OK', row['dominant'], row2['chips'])\n")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=900,
                          cwd=os.path.dirname(os.path.dirname(

@@ -20,10 +20,6 @@ def test_matmul_flops_exact():
     assert s.flops == pytest.approx(2 * 256 * 512 * 128, rel=0.01)
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="Compiled.cost_analysis returns a per-device LIST on jax < 0.5; "
-           "the dict comparison below needs the new structure")
 def test_scan_loop_trip_count_multiplies():
     """THE bug this module exists for: XLA cost_analysis counts while
     bodies once; ours multiplies by the derived trip count."""
