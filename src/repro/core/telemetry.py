@@ -1,10 +1,11 @@
-"""Unified telemetry: request spans, a deterministic metrics registry, and
-latency attribution across all three drivers (DESIGN.md §16).
+"""Unified telemetry: request spans, a deterministic metrics registry,
+latency attribution across all three drivers, and the token engine's
+phase spans (DESIGN.md §16).
 
-Three pieces, all pure observers — nothing in here feeds a scheduling
-decision, holds a wall clock, or draws randomness, so enabling telemetry
-cannot move the golden behavior fingerprint or the cross-driver decision
-parity by a single bit:
+Four pieces, all pure observers — nothing in here feeds a scheduling
+decision or draws randomness, and the only clock read is the one a driver
+hands in, so enabling telemetry cannot move the golden behavior
+fingerprint or the cross-driver decision parity by a single bit:
 
 * ``Telemetry``        — request spans. Drivers append flat event tuples to
                          ``Telemetry.raw`` (one list append on the hot
@@ -29,6 +30,13 @@ parity by a single bit:
                          ``[t_admit, t_close]`` exactly, so per-component
                          sums reconcile with measured end-to-end latency
                          by construction (bench_telemetry certifies it).
+* ``Phase``            — one timed phase of an engine (``Telemetry.phase``):
+                         an interval on the driver's clock with its parent
+                         phase, stage, token boundary and counts, kept in
+                         ``Telemetry.phases`` and written at the same time
+                         as a ``jax.profiler.TraceAnnotation`` carrying the
+                         phase's sequence number ``n``, so that a profiler
+                         trace holds it beside the device's events.
 
 Event-tuple vocabulary (first element is the kind):
 
@@ -60,10 +68,12 @@ import json
 import math
 import threading
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Counter", "Gauge", "Log2Histogram", "WindowSeries",
-           "MetricsRegistry", "Span", "Telemetry"]
+           "MetricsRegistry", "Span", "Phase", "Telemetry"]
 
 
 # ---------------------------------------------------------------------------
@@ -399,6 +409,64 @@ class Span:
                 "events": [[k, t, s] for k, t, s in self.events]}
 
 
+class Phase:
+    """One timed phase of an engine, as a context manager and its record.
+
+    ``t0``/``t1`` are the driver's clock at entry and exit; ``parent`` is
+    the ``n`` of the phase open around this one (-1 at the top), whose
+    stage and boundary a child takes when it names none. The same ``n``
+    (with ``s`` = stage, ``b`` = boundary) goes on the phase's
+    ``TraceAnnotation``, which is opened before ``t0`` is read and closed
+    after ``t1``, so a traced phase holds its record. ``counts`` are the
+    phase's own tallies (rows, tokens, leaves, ...).
+    """
+    __slots__ = ("name", "t0", "t1", "parent", "stage", "boundary", "n",
+                 "counts", "_telemetry", "_clock", "_annotation")
+
+    def __init__(self, telemetry: "Telemetry", name: str,
+                 clock: Callable[[], float], stage: Optional[int],
+                 boundary: Optional[int], counts: Dict[str, int]):
+        self.name = name
+        self.stage = stage
+        self.boundary = boundary
+        self.counts = counts
+        self.t0 = self.t1 = 0.0
+        self.parent = -1
+        self.n = -1
+        self._telemetry = telemetry
+        self._clock = clock
+        self._annotation = None
+
+    def __enter__(self) -> "Phase":
+        telem = self._telemetry
+        up = telem._open[-1] if telem._open else None
+        if up is not None:
+            self.parent = up.n
+        if self.stage is None:
+            self.stage = -1 if up is None else up.stage
+        if self.boundary is None:
+            self.boundary = -1 if up is None else up.boundary
+        self.n = len(telem.phases)
+        telem.phases.append(self)
+        telem._open.append(self)
+        self._annotation = TraceAnnotation(self.name, n=self.n, s=self.stage,
+                                           b=self.boundary)
+        self._annotation.__enter__()
+        self.t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = self._clock()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        self._telemetry._open.pop()
+
+    def to_dict(self) -> Dict:
+        return {"name": self.name, "n": self.n, "parent": self.parent,
+                "stage": self.stage, "boundary": self.boundary,
+                "t0": self.t0, "t1": self.t1, "counts": dict(self.counts)}
+
+
 class SpanAccountingError(AssertionError):
     """A span was closed twice, closed without being admitted, or closed
     with an unknown state — accounting bugs the conservation tests exist
@@ -406,12 +474,14 @@ class SpanAccountingError(AssertionError):
 
 
 class Telemetry:
-    """Flat event log + span fold + attribution, sharing one registry.
+    """Flat event log + span fold + attribution + engine phases, sharing
+    one registry.
 
     Hot-path contract: drivers append tuples to ``self.raw`` (hoist
     ``telem.raw.append`` into a local). Everything else — span
     construction, conservation, attribution, registry histograms — runs
-    in ``finalize()``, off the decision loop.
+    in ``finalize()``, off the decision loop. Phases (``phase()``) are
+    recorded as they open and are not folded.
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
@@ -425,6 +495,10 @@ class Telemetry:
         # per-admit append on the hot loop; finalize() runs them first
         self.deferred: List = []
         self._finalized = False
+        # engine phases, in the order they opened (``Phase.n`` indexes
+        # this list); ``_open`` is the stack of phases not yet closed
+        self.phases: List[Phase] = []
+        self._open: List[Phase] = []
 
     # ----------------------------------------------------- cold-path API
     # (convenience wrappers; hot loops append tuples directly)
@@ -438,6 +512,14 @@ class Telemetry:
 
     def close(self, t: float, sid: int, state: str) -> None:
         self.raw.append(("close", t, sid, state))
+
+    def phase(self, name: str, clock: Callable[[], float],
+              stage: Optional[int] = None, boundary: Optional[int] = None,
+              **counts: int) -> Phase:
+        """A phase to open with ``with``: recorded in ``phases`` and
+        annotated for the profiler, timed by ``clock``. Phases nest on one
+        thread; the innermost open phase is the new one's parent."""
+        return Phase(self, name, clock, stage, boundary, counts)
 
     # ------------------------------------------------------------ folding
 

@@ -50,15 +50,39 @@ Two execution modes (DESIGN.md §14):
 Decisions are bit-identical across the two modes and the token DES by
 construction: every executor folds the same float64 ``StreamingCertainty``
 over the same per-token gap stream and consults the same
-``ContinuousBatcher`` at the same token counts. The engine advances in
-deterministic logical steps (no wall clock): timing lives in the DES
+``ContinuousBatcher`` at the same token counts. Decisions advance in
+deterministic logical steps and read no clock; timing lives in the DES
 (``ServingSimulator.run_token_trace``), which stays the decision oracle.
+
+Telemetry (``TokenEngine(telemetry=...)``, DESIGN.md §16) is the one
+place the engine reads a clock, the caller's (``clock``, by default
+``time.perf_counter``). It stamps the request events and times the
+phases of each token boundary (``Telemetry.phase``), each also a
+``jax.profiler.TraceAnnotation``:
+
+* ``engine.admit`` (a boundary's joins at one stage, when the batcher
+  admits any), holding ``slot.prefill`` (the bucketed prefill's dispatch,
+  or the exact-length fallback loop; counts ``rows``, ``batch_bucket``,
+  ``len_bucket`` (0: exact length), ``tokens`` and ``padded``),
+  ``slot.join`` (the cache scatter, then the fused loop's row updates)
+  and ``slot.fetch`` (the host blocked on the first tokens and gaps);
+* ``engine.decode`` (one fused decode call; counts ``rows``, ``k``),
+  holding ``slot.dispatch`` (the active-mask upload and the executable
+  call), ``slot.fetch`` (the host blocked on the traces) and
+  ``engine.decide`` (the boundary replay and the leaves; counts
+  ``leaves``, ``escalations``).
+
+The reference loop's decode steps are not timed. With ``telemetry=None``
+no annotation is made and the clock is never read.
 """
 from __future__ import annotations
 
+import contextlib
+import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import (Deque, Dict, List, Optional, Sequence, Set, Tuple)
+from typing import (Callable, Deque, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +122,18 @@ def greedy_generate(params, cfg, prompt: np.ndarray, max_new: int
             params, cfg, step, cache, np.asarray([pos], np.int32))
         pos += 1
     return np.asarray(out, np.int32), np.asarray(gaps, np.float64)
+
+
+_NO_PHASE = contextlib.nullcontext()
+
+
+def _phase(telemetry, clock, name: str, stage: Optional[int] = None,
+           boundary: Optional[int] = None, **counts: int):
+    """``telemetry.phase(...)``, or a no-op context (bound to None) when
+    there is no telemetry."""
+    if telemetry is None:
+        return _NO_PHASE
+    return telemetry.phase(name, clock, stage, boundary, **counts)
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -147,6 +183,9 @@ class SlotEngine:
         self.active = np.zeros(n_slots, bool)
         self.stats = SlotEngineStats()
         self._vocab = cfg.vocab_size
+        # set by the TokenEngine that drives this pool
+        self.telemetry = None
+        self.clock: Callable[[], float] = time.perf_counter
         # --- reference executables (PR-7 loop, parity baseline) ---------
         # each jitted entry point is a named function: compile logs and
         # profiler traces report it by that name
@@ -290,41 +329,56 @@ class SlotEngine:
             raise RuntimeError(
                 f"{self.name}: {n} joiners for {len(self.free)} free slots")
         lb = self._len_bucket(max(p.size for p in prompts))
+        telem, clock = self.telemetry, self.clock
         if self._bucketed is None or (
                 self.cfg.sliding_window > 0
                 and lb >= min(self.cfg.sliding_window, self.max_len)):
             # exact-length fallback: pads are not semantically invisible
             # here (SSM state / MoE routing / window ring aliasing)
             slots, toks, gaps = [], [], []
-            for p in prompts:
-                slot, logits = self.prefill_into_slot(p)
-                slots.append(slot)
-                toks.append(int(np.argmax(logits)))
-                gaps.append(float(np.asarray(top2_gap(logits[None, :]))[0]))
+            with _phase(telem, clock, "slot.prefill") as ph:
+                for p in prompts:
+                    slot, logits = self.prefill_into_slot(p)
+                    slots.append(slot)
+                    toks.append(int(np.argmax(logits)))
+                    gaps.append(
+                        float(np.asarray(top2_gap(logits[None, :]))[0]))
+                if ph is not None:
+                    real = sum(p.size for p in prompts)
+                    ph.counts.update(rows=n, batch_bucket=1, len_bucket=0,
+                                     tokens=real, padded=real)
             toks = np.asarray(toks, np.int32)
             gaps = np.asarray(gaps, np.float32)
-            self._join_rows(slots, np.asarray([p.size for p in prompts]),
-                            toks, gaps)
+            with _phase(telem, clock, "slot.join"):
+                self._join_rows(slots, np.asarray([p.size for p in prompts]),
+                                toks, gaps)
             return slots, toks, gaps
-        bb = self._batch_bucket(n)
-        arr = np.zeros((bb, lb), np.int32)
-        lens = np.ones((bb,), np.int32)
-        for i, p in enumerate(prompts):
-            arr[i, :p.size] = p
-            lens[i] = p.size
-        tok_d, gap_d, cache1 = self._bucketed(self.params, arr, lens)
+        with _phase(telem, clock, "slot.prefill") as ph:
+            bb = self._batch_bucket(n)
+            arr = np.zeros((bb, lb), np.int32)
+            lens = np.ones((bb,), np.int32)
+            for i, p in enumerate(prompts):
+                arr[i, :p.size] = p
+                lens[i] = p.size
+            tok_d, gap_d, cache1 = self._bucketed(self.params, arr, lens)
+            if ph is not None:
+                ph.counts.update(rows=n, batch_bucket=bb, len_bucket=lb,
+                                 tokens=int(lens[:n].sum()), padded=bb * lb)
         slots = [self.free.pop() for _ in range(n)]
-        rows = jnp.asarray(np.asarray(slots, np.int32))
-        self.cache = jax.tree.map(
-            lambda pool, new: pool.at[:, rows].set(
-                new[:, :n].astype(pool.dtype)), self.cache, cache1)
-        toks = np.asarray(tok_d[:n])
-        gaps = np.asarray(gap_d[:n])
+        with _phase(telem, clock, "slot.join"):
+            rows = jnp.asarray(np.asarray(slots, np.int32))
+            self.cache = jax.tree.map(
+                lambda pool, new: pool.at[:, rows].set(
+                    new[:, :n].astype(pool.dtype)), self.cache, cache1)
+        with _phase(telem, clock, "slot.fetch"):
+            toks = np.asarray(tok_d[:n])
+            gaps = np.asarray(gap_d[:n])
         plens = lens[:n]
         for slot, plen in zip(slots, plens):
             self.pos[slot] = plen
             self.active[slot] = True
-        self._join_rows(slots, plens, toks, gaps)
+        with _phase(telem, clock, "slot.join"):
+            self._join_rows(slots, plens, toks, gaps)
         self.stats.prefill_calls += 1
         self.stats.prefill_prompts += n
         self.stats.prefill_shapes.add((bb, lb))
@@ -422,18 +476,20 @@ class SlotEngine:
             raise ValueError(
                 f"{self.name}: a {k}-step scan overruns a "
                 f"{self.max_len}-token slot")
-        if self._active_dirty:
-            self.dev_active = jnp.asarray(self.active)
-            self._active_dirty = False
-        fn = self._get_fused(mode, beta)
-        tt, gt, ct, self.dev_tok, self.cache, self.dev_pos, self._fold = fn(
-            self.params, self.dev_tok, self.cache, self.dev_pos,
-            self.dev_active, self._fold, k=k)
+        with _phase(self.telemetry, self.clock, "slot.dispatch"):
+            if self._active_dirty:
+                self.dev_active = jnp.asarray(self.active)
+                self._active_dirty = False
+            fn = self._get_fused(mode, beta)
+            (tt, gt, ct, self.dev_tok, self.cache, self.dev_pos,
+             self._fold) = fn(self.params, self.dev_tok, self.cache,
+                              self.dev_pos, self.dev_active, self._fold, k=k)
         self.pos[self.active] += k
         self.stats.decode_calls += 1
         self.stats.decode_steps += k
         self.stats.bytes_to_host += k * self.n_slots * 12  # tok+gap+cert
-        return np.asarray(tt), np.asarray(gt), np.asarray(ct)
+        with _phase(self.telemetry, self.clock, "slot.fetch"):
+            return np.asarray(tt), np.asarray(gt), np.asarray(ct)
 
 
 @dataclass
@@ -486,6 +542,15 @@ class TokenEngine:
     K-1 tokens are discarded when a row decides mid-scan, and every
     decision is re-derived from the returned gap trace at the same token
     counts as a K=1 run.
+
+    ``telemetry`` (a ``core.telemetry.Telemetry``, shared with the stage
+    engines) observes on ``clock``, and only observes. Its request events
+    carry ``clock()`` seconds: ``admit`` when the engine takes custody of
+    the request (in ``serve`` its queue entry; for a caller that fills
+    ``waiting[0]`` itself and calls ``_admit``, its first stage-0 admit),
+    ``fire`` at the start of each stage's admit that takes it,
+    ``escalate`` when it enters the next stage's queue, ``close`` when it
+    resolves. Its phases time each boundary (module docstring).
     """
 
     def __init__(self, stages: Sequence[SlotEngine], gear: Gear,
@@ -493,7 +558,8 @@ class TokenEngine:
                  min_tokens: int = 4, early_margin: float = 0.5,
                  stream_mode: str = "ewma", beta: float = 0.35,
                  mode: str = "fused", spec_k: int = 1,
-                 k_guard_slack: float = 1.5, telemetry=None):
+                 k_guard_slack: float = 1.5, telemetry=None,
+                 clock: Callable[[], float] = time.perf_counter):
         if not stages:
             raise ValueError("TokenEngine needs at least one SlotEngine")
         if tuple(e.name for e in stages) != tuple(gear.cascade.models):
@@ -518,11 +584,14 @@ class TokenEngine:
         self.spec_k = spec_k
         self.k_guard_slack = k_guard_slack
         self.spec_discarded = 0       # speculative tokens thrown away
-        # pure observer (core/telemetry.py): span times are LOGICAL step
-        # numbers (this engine has no clock); occupancy gauges and the
+        # pure observer (core/telemetry.py): request events and phases on
+        # ``clock``, which no decision reads; occupancy gauges and the
         # spec-discard counter live in the shared registry
         self.telemetry = telemetry
+        self.clock = clock
         self._traw = telemetry.raw.append if telemetry is not None else None
+        for e in self.stages:
+            e.telemetry, e.clock = telemetry, clock
 
     # ------------------------------------------------------------- serve
 
@@ -538,13 +607,13 @@ class TokenEngine:
             results[r.rid] = res
             waiting[0].append((r, res))
             if self._traw is not None:
-                self._traw(("admit", 0.0, r.rid, 0, 0, ""))
+                self._traw(("admit", self.clock(), r.rid, 0, 0, ""))
 
         step = 0
         while any(waiting) or any(act):
             for si, eng in enumerate(self.stages):
                 # admission at the token boundary: prefill phase first
-                self._admit(si, eng, waiting, act, step)
+                self._admit(si, eng, waiting, act, step, custody=False)
                 if not act[si]:
                     continue
                 if self.mode == "reference":
@@ -556,15 +625,27 @@ class TokenEngine:
 
     # ------------------------------------------------------ admit phase
 
-    def _admit(self, si: int, eng: SlotEngine, waiting, act, step: int
-               ) -> None:
+    def _admit(self, si: int, eng: SlotEngine, waiting, act, step: int,
+               custody: bool = True) -> None:
+        """The boundary's joins at stage ``si``. With ``custody``, stage-0
+        joiners enter the engine's custody here (telemetry's ``admit``);
+        ``serve`` stamps that at queue entry instead."""
         k = self.batchers[si].admit(eng.n_active, len(waiting[si]))
         if not k:
             return
         pairs = [waiting[si].popleft() for _ in range(k)]
-        if self._traw is not None:
-            self._traw(("fire", float(step), si,
-                        tuple(req.rid for req, _ in pairs)))
+        with _phase(self.telemetry, self.clock, "engine.admit", si,
+                    step) as ph:
+            if ph is not None:
+                rids = tuple(req.rid for req, _ in pairs)
+                if custody and si == 0:
+                    for rid in rids:
+                        self._traw(("admit", ph.t0, rid, 0, 0, ""))
+                self._traw(("fire", ph.t0, si, rids))
+            self._join(si, eng, pairs, act, step)
+
+    def _join(self, si: int, eng: SlotEngine, pairs, act, step: int
+              ) -> None:
         if self.mode == "reference":
             joined = []
             for req, res in pairs:
@@ -607,19 +688,12 @@ class TokenEngine:
             a.res.first_token_step = -1
             waiting[hop.next_stage].append((a.req, a.res))
             if self._traw is not None:
-                self._traw(("escalate", float(step), a.req.rid, si))
+                self._traw(("escalate", self.clock(), a.req.rid, si))
         else:
             a.res.resolver = si
             a.res.done_step = step
             if self._traw is not None:
-                self._traw(("close", float(step), a.req.rid, "completed"))
-                reg = self.telemetry.registry
-                reg.histogram("engine_ttft_steps").observe(
-                    float(a.res.first_token_step + 1))
-                ntok = len(a.res.tokens)
-                if ntok > 1:
-                    reg.histogram("engine_tpot_steps").observe(
-                        (step - a.res.first_token_step) / (ntok - 1))
+                self._traw(("close", self.clock(), a.req.rid, "completed"))
         if self.telemetry is not None:
             self.telemetry.registry.gauge(
                 "kv_slots_active", model=eng.name).set(eng.n_active)
@@ -674,8 +748,25 @@ class TokenEngine:
         steps; the host sees (K, B) token/gap/certainty traces and
         replays boundary decisions over them at the same token counts."""
         k = self._choose_k(si, eng, waiting, act)
-        tok_t, gap_t, _cert_t = eng.decode_fused(
-            k, mode=self.stream_mode, beta=self.beta)
+        telem, clock = self.telemetry, self.clock
+        with _phase(telem, clock, "engine.decode", si, step,
+                    rows=len(act[si]), k=k):
+            tok_t, gap_t, _cert_t = eng.decode_fused(
+                k, mode=self.stream_mode, beta=self.beta)
+            with _phase(telem, clock, "engine.decide") as ph:
+                leaves = self._replay(si, act, tok_t, gap_t, k)
+                for _, _, a, hop in leaves:
+                    self._leave(si, eng, a, hop, waiting, act, step)
+                if ph is not None:
+                    ph.counts.update(leaves=len(leaves), escalations=sum(
+                        getattr(hop, "next_stage", None) is not None
+                        for _, _, _, hop in leaves))
+
+    def _replay(self, si: int, act, tok_t: np.ndarray, gap_t: np.ndarray,
+                k: int) -> List[Tuple[int, int, _Active, object]]:
+        """Append each row's consumed tokens and gaps from the (k, B)
+        traces; returns the rows that leave, in (token count, row) order —
+        the order a single-step loop would have produced them in."""
         leaves: List[Tuple[int, int, _Active, object]] = []
         for order, a in enumerate(act[si]):
             start = len(a.res.tokens)
@@ -692,11 +783,8 @@ class TokenEngine:
                 if self.telemetry is not None and k > used:
                     self.telemetry.registry.counter(
                         "spec_discarded_tokens").inc(k - used)
-        # apply leaves in (token count, row) order — the order a
-        # single-step loop would have produced them in
         leaves.sort(key=lambda e: (e[0], e[1]))
-        for _, _, a, hop in leaves:
-            self._leave(si, eng, a, hop, waiting, act, step)
+        return leaves
 
     # ------------------------------------------------------------- stats
 
