@@ -301,6 +301,18 @@ the paper's serving technique; memory-bound)
   math survives only inside the manual shard_map flash-decode body (H2
   it. 2), where layouts are explicit — a crisp lesson that einsum
   *structure* is part of the sharding interface, not just the math.
+* **One device, revisited.** The served decode path has no
+  mesh, and there a v5e chip trace refuted the refutation. In the
+  qwen2-0.5b -> h2o-danube-1.8b cascade (16 slots x 2048 a stage) the
+  repeat form's four ops (K/V broadcast to the query heads in f32 and
+  bf16, and their two multiply-reduces) took 1.21 s of ~2.53 s busy,
+  ~48% of device time (PERF_LEDGER.jsonl). Where no mesh is set,
+  `decode_attention` now attends grouped over the bf16 cache
+  (`sdpa_gqa`): danube's attention is ~2.9 ms of a 20.5 ms fused call
+  on the device, and the cascade's `itl_p95_ms` fell from 105.6 to
+  54.9 ms (medians of three runs each side; PERF.md §6). The GSPMD
+  result above stands for every path with a mesh, which keeps the
+  repeat form.
 
 ### Beyond-paper optimisations shipped as defaults
 

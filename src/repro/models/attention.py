@@ -126,6 +126,12 @@ def causal_mask(sq: int, sk: int, window: int = 0,
     return m
 
 
+def _mesh_set() -> bool:
+    from repro.distributed.context import get_context
+    ctx = get_context()
+    return ctx is not None and ctx.mesh is not None
+
+
 def _seq_parallel_attention(cfg: ModelConfig) -> bool:
     """Sequence-parallel full-seq attention when the head count does not
     tile the model axis: left to itself, GSPMD shards the CONTRACTING
@@ -245,8 +251,6 @@ def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
         v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot,
                                                 axis=1)
 
-    kr = _repeat_kv(k, cfg.num_heads)
-    vr = _repeat_kv(v, cfg.num_heads)
     idx = jnp.arange(c_len)
     if ragged:
         if cfg.sliding_window > 0:
@@ -262,11 +266,19 @@ def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
         else:
             valid = idx <= cache_index
         mask = valid[None, None, None, :]  # (1,1,1,C)
-    # repeat_kv form: under GSPMD the grouped 5-dim einsum breaks head-dim
-    # sharding propagation and replicates the cache (+4.9x bytes measured,
-    # §Perf H4 refuted); the grouped math lives in the shard_map
-    # flash-decode body where layouts are explicit.
-    out = sdpa(q, kr, vr, mask)
+    if _mesh_set():
+        # repeat_kv form: under GSPMD the grouped 5-dim einsum breaks
+        # head-dim sharding propagation and replicates the cache (+4.9x
+        # bytes measured, §Perf H4 refuted); on a mesh the grouped math
+        # lives in the shard_map flash-decode body where layouts are
+        # explicit.
+        out = sdpa(q, _repeat_kv(k, cfg.num_heads),
+                   _repeat_kv(v, cfg.num_heads), mask)
+    else:
+        # one device: attend grouped over the cache as it stands. The
+        # repeat to H heads (upcast to f32 in the fused step) was ~48% of
+        # the qwen2 -> danube cascade's device time on a v5e (PERF.md §6).
+        out = sdpa_gqa(q, k, v, mask)
     out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim) @ p["wo"]
     return out, {"k": k, "v": v}
 

@@ -1,13 +1,17 @@
 """Per-architecture smoke tests (assignment requirement): reduced config,
 one forward/train step on CPU, output shapes + no NaNs; plus prefill/decode
 consistency against the full forward."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_smoke_config
+from repro.models import attention as A
 from repro.models import model as M
+from repro.models.common import ArrayFactory, apply_rope
 
 
 def make_batch(cfg, b=2, s=24, with_labels=True, rng=0):
@@ -121,3 +125,52 @@ def test_block_pattern_structure():
     assert [sp.ffn for sp in jamba].count("moe") == 4
     llama4 = block_pattern(get_config("llama4-maverick-400b-a17b"))
     assert [sp.ffn for sp in llama4] == ["dense", "moe"]
+
+
+@pytest.mark.parametrize("arch,heads,index", [
+    ("qwen2-0.5b", 8, [0, 5, 17, 31]),        # ragged (B,) depths
+    ("qwen2-0.5b", 8, 11),                    # one depth for the batch
+    ("h2o-danube-1.8b", 8, [3, 63, 64, 100]),  # ring buffer, rows past wrap
+    ("olmo-1b", 4, [0, 9, 20, 31]),            # G == 1 (MHA)
+], ids=["ragged", "scalar", "ring", "mha"])
+def test_grouped_decode_matches_repeat_form(arch, heads, index):
+    """One-device decode attends grouped over the bf16 cache; it must give
+    what the repeat form gives on the same written cache and mask."""
+    cfg = dataclasses.replace(get_smoke_config(arch), num_heads=heads)
+    g = cfg.num_heads // cfg.num_kv_heads
+    assert g == (1 if arch == "olmo-1b" else 4)
+    p = A.make_attention_params(ArrayFactory(jax.random.PRNGKey(0), False),
+                                cfg)
+    b, c_len = 4, A.kv_cache_len(cfg, 128 if cfg.sliding_window else 32)
+    shape = (b, c_len, cfg.num_kv_heads, cfg.head_dim)
+    cache = {n: jax.random.normal(jax.random.PRNGKey(i + 1), shape
+                                  ).astype(jnp.bfloat16)
+             for i, n in enumerate("kv")}
+    x = jax.random.normal(jax.random.PRNGKey(3), (b, 1, cfg.d_model)
+                          ).astype(jnp.bfloat16)
+    idx = jnp.asarray(index, jnp.int32)
+
+    step = jax.jit(lambda c: A.decode_attention(p, cfg, x, c, idx))
+    out, new = step(cache)
+    hlo = step.lower(cache).as_text()
+    assert f"{b}x{c_len}x{cfg.num_kv_heads}x{g}x{cfg.head_dim}" not in hlo
+
+    depth = jnp.broadcast_to(idx, (b,))
+    q, _, _ = A._project_qkv(p, cfg, x)
+    q = apply_rope(q, depth[:, None], cfg.rope_theta)
+    pos = jnp.arange(c_len)[None, :]
+    if cfg.sliding_window:
+        valid = (pos <= depth[:, None] % c_len) | (depth[:, None] >= c_len)
+    else:
+        valid = pos <= depth[:, None]
+    ref = A.sdpa(q, A._repeat_kv(new["k"], cfg.num_heads),
+                 A._repeat_kv(new["v"], cfg.num_heads),
+                 valid[:, None, None, :])
+    ref = ref.reshape(b, 1, cfg.num_heads * cfg.head_dim) @ p["wo"]
+    # same f32 scores and softmax, bf16 probabilities, bf16 PV and output
+    # projection: the forms may differ by an ulp or two of bf16 at the
+    # output's scale
+    ref32 = np.asarray(ref.astype(jnp.float32))
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref32,
+                               rtol=0, atol=4 * 2.0 ** -8
+                               * float(np.max(np.abs(ref32))))

@@ -103,6 +103,28 @@ with use_context(context_for_mesh(mesh4)):
 err5 = float(jnp.max(jnp.abs(out5 - ref5)))
 assert err5 < 1e-3, f"seq-parallel err {err5}"
 print("SEQ_PARALLEL_OK", err5)
+
+# 6) dense decode with a mesh set keeps the repeat form (Perf H4: the
+#    grouped einsum replicates the cache under GSPMD); with none it attends
+#    grouped, to the same numbers
+cfg6 = get_smoke_config("h2o-danube-1.8b")  # 4 heads over 2 kv; SWA ring
+p6 = M.init_params(cfg6, jax.random.PRNGKey(5))
+c6 = jax.tree.map(
+    lambda a: jax.random.normal(jax.random.PRNGKey(6), a.shape).astype(
+        a.dtype), M.init_cache(cfg6, 4, 32))
+t6 = jnp.full((4, 1), 7, jnp.int32)
+i6 = jnp.asarray([0, 3, 40, 31], jnp.int32)
+step6 = jax.jit(lambda p, c: M.decode_step(p, cfg6, t6, c, i6))
+group = "4x32x2x2x32"  # K/V repeated to (B, C, KV, G, hd)
+assert group not in step6.lower(p6, c6).as_text()
+ref6, _ = step6(p6, c6)
+with use_context(context_for_mesh(mesh4)):
+    step6m = jax.jit(lambda p, c: M.decode_step(p, cfg6, t6, c, i6))
+    assert group in step6m.lower(p6, c6).as_text()
+    out6, _ = step6m(p6, c6)
+err6 = float(jnp.max(jnp.abs(out6 - ref6)))
+assert err6 < 5e-2, f"mesh decode err {err6}"
+print("MESH_REPEAT_OK", err6)
 """
 
 
@@ -119,6 +141,7 @@ def test_multidevice_subprocess():
     assert "ZERO1_SHARDING_OK" in res.stdout
     assert "FLASH_DECODE_OK" in res.stdout
     assert "SEQ_PARALLEL_OK" in res.stdout
+    assert "MESH_REPEAT_OK" in res.stdout
 
 
 @pytest.mark.slow

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e (nothing runs): the served top-2-gap
 kernel at the batch and vocab sizes the engines use, and the fused decode
-step and bucketed prefill at qwen2-0.5b widths with the kernel inside.
+step at qwen2-0.5b and h2o-danube-1.8b widths and the bucketed prefill at
+qwen2-0.5b widths, with the kernel inside.
 
 Interpret mode, which the kernel tests use, accepts block shapes that the
 chip's compiler refuses; these compiles are what catches that here."""
@@ -51,17 +52,22 @@ def test_top2gap_compiles(one_chip, b, v):
     assert "tpu_custom_call" in hlo
 
 
+def _cut(name):
+    # published widths, depth cut to two layers: compile time, not shapes
+    return get_config(name).scaled(num_layers=2)
+
+
 @pytest.fixture(scope="module")
 def qwen2_cut():
-    # published widths, depth cut to two layers: compile time, not shapes
-    return get_config("qwen2-0.5b").scaled(num_layers=2)
+    return _cut("qwen2-0.5b")
 
 
-def test_decode_fused_step_compiles_with_kernel(one_chip, qwen2_cut,
-                                                kernel_backend):
-    cfg, b = qwen2_cut, 16
+@pytest.mark.parametrize("name", ["qwen2-0.5b", "h2o-danube-1.8b"])
+def test_decode_fused_step_compiles_with_kernel(one_chip, kernel_backend,
+                                                name):
+    cfg, b, c_len = _cut(name), 16, 2048
     params = _spec(M.init_params(cfg, spec_only=True), one_chip)
-    cache = _spec(M.init_cache(cfg, b, 2048, spec_only=True), one_chip)
+    cache = _spec(M.init_cache(cfg, b, c_len, spec_only=True), one_chip)
     fold = _spec(jax.eval_shape(lambda: device_fold_init(b)), one_chip)
     rows = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
     active = jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=one_chip)
@@ -70,6 +76,11 @@ def test_decode_fused_step_compiles_with_kernel(one_chip, qwen2_cut,
     hlo = step.lower(params, rows, cache, rows, active, fold) \
         .compile().as_text()
     assert "tpu_custom_call" in hlo
+    # one device attends grouped: the cache is never broadcast to its
+    # query heads (B, C, KV, G, hd)
+    kv = cfg.num_kv_heads
+    group = f"[{b},{c_len},{kv},{cfg.num_heads // kv},{cfg.head_dim}]"
+    assert group not in hlo
 
 
 def test_bucketed_prefill_compiles_with_kernel(one_chip, qwen2_cut,
