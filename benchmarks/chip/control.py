@@ -7,11 +7,11 @@
 One process serves the cell at its own size and load on each seed in turn
 (the engines are built and warmed once; the weights and the gear are
 the same in every seed, which only orders the mix's requests), with a
-short window, and compares what was served with
-the plain reference as every run does: these are the program's readings,
-whose largest over a dozen seeds is a limit's lower reading. On the first
-``--control-seeds`` seeds the same sample is also read with the reference
-computed in int8 and in fp8 in the program's place (the precision
+short window, and compares what was served with the plain reference of
+each stage model's family as every run does: these are the program's
+readings, whose largest over a dozen seeds is a limit's lower reading. On
+the first ``--control-seeds`` seeds the same sample is also read with the
+reference computed in int8 and in fp8 in the program's place (the precision
 control), whose smallest reading is a limit's upper one; each control is
 also held to the cell's limits in the program's place, and has to come out
 not correct. ``--rate`` serves another rate than the mix's file states

@@ -9,7 +9,11 @@ stage 0's queue, and when nothing is active or waiting it sleeps until
 the next one is due. This is the one place the benchmark reaches into the
 engine; a public clocked entry would replace it.
 
-Every call is stamped with ``time.perf_counter()`` after it returns. Both
+Every call is stamped with ``time.perf_counter()`` after it returns, and
+records the change it made to each numeric field of its stage engine's
+``stats`` (``Call.counts``): whatever an engine counts, such as prompts
+prefilled or, in an engine that routes, the experts a step reads, reaches
+the family's counts with no edit here. Both
 calls end in ``np.asarray`` on the device's outputs, so the stamps follow
 the device. Host phases are wrapped in ``jax.profiler.TraceAnnotation`` so
 that a trace can name what the host was doing in each idle gap of the
@@ -54,6 +58,17 @@ class Call:
     padded_rows: int = 0      # rows the executable ran (batch bucket)
     depths: List[int] = field(default_factory=list)   # decode: per row
     boundary: int = -1
+    counts: Dict[str, float] = field(default_factory=dict)
+
+
+def numbers(stats) -> Dict[str, float]:
+    """The numeric fields of an engine's ``stats`` (sets left out)."""
+    return {k: v for k, v in vars(stats).items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+def change(before: Dict[str, float], stats) -> Dict[str, float]:
+    return {k: v - before.get(k, 0) for k, v in numbers(stats).items()}
 
 
 class OpenLoop:
@@ -110,6 +125,7 @@ class OpenLoop:
         self.queue_len.append((now, tuple(len(w) for w in self.waiting)))
         for si, eng in enumerate(te.stages):
             queue = list(self.waiting[si])
+            before = numbers(eng.stats)
             t0 = clock()
             with TraceAnnotation(f"admit.s{si}", b=self.boundary):
                 te._admit(si, eng, self.waiting, self.act, self.boundary)
@@ -120,7 +136,8 @@ class OpenLoop:
                     "admit", si, t0, t1, len(joined),
                     prompt_lens=[int(r.prompt.size) for r, _ in joined],
                     padded_rows=eng._batch_bucket(len(joined)),
-                    boundary=self.boundary))
+                    boundary=self.boundary,
+                    counts=change(before, eng.stats)))
                 for req, _ in joined:
                     rec = self.records[req.rid]
                     rec.admit_start[si] = t0
@@ -129,6 +146,7 @@ class OpenLoop:
                 continue
             rows = list(self.act[si])
             depths = [int(eng.pos[a.slot]) for a in rows]
+            before = numbers(eng.stats)
             t0 = clock()
             with TraceAnnotation(f"decode.s{si}", b=self.boundary):
                 te._step_fused(si, eng, self.waiting, self.act,
@@ -138,7 +156,8 @@ class OpenLoop:
                 self.calls.append(Call("decode", si, t0, t1, len(rows),
                                        padded_rows=eng.n_slots,
                                        depths=depths,
-                                       boundary=self.boundary))
+                                       boundary=self.boundary,
+                                       counts=change(before, eng.stats)))
                 for a in rows:
                     rec = self.records[a.req.rid]
                     rec.stamps[si].append(t1)

@@ -19,7 +19,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-import costs
 import tracing
 import traffic as T
 from compilelog import CompileLog
@@ -54,19 +53,12 @@ def stage_configs(cell: Cell, model_configs=None):
     from repro.configs import get_config
     cfgs = list(model_configs or [get_config(n)
                                   for n in cell.config["stages"]])
-    # The shapes the program serves have to be the file's. The norm's
-    # epsilon is left to the comparison: the reference computes the
-    # file's (published) value, so a program that serves another one
-    # shows in ``correct``.
+    # the shapes the program serves have to be the file's, as the model's
+    # family compares them
     for cfg in cfgs:
-        a = costs.arch(cell.config["models"][cfg.name])
-        served = (cfg.num_layers, cfg.d_model, cfg.num_heads,
-                  cfg.num_kv_heads, cfg.head_dim, cfg.d_ff, cfg.vocab_size,
-                  cfg.tie_embeddings, cfg.qkv_bias, cfg.rope_theta,
-                  cfg.sliding_window)
-        stated = (a.layers, a.d_model, a.heads, a.kv_heads, a.head_dim,
-                  a.d_ff, a.vocab, a.tied, a.qkv_bias, a.rope_theta,
-                  a.window)
+        family = cell.family(cfg.name)
+        served = family.served(cfg)
+        stated = family.stated(family.arch(cell.config["models"][cfg.name]))
         if served != stated:
             raise ValueError(f"{cfg.name}: the program serves {served}, "
                              f"the configuration file states {stated}")
@@ -181,10 +173,12 @@ class RunData:
     calls: List[Call]
     stages: List[str]
     n_slots: int
-    archs: List[costs.Arch]
+    archs: list                  # each stage model's, from its family
+    families: list               # each stage model's family module
     peak: dict
     trace: Optional[dict] = None
     t_end: float = 0.0           # the drain's end
+    telemetry: Optional[object] = None   # the engine's, in a traced run
 
     def window_records(self) -> List[Record]:
         return [r for r in self.records if self.win0 <= r.due < self.win1]
@@ -332,7 +326,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
         for eng, p in zip(engines, params):
             eng.params = p
     gear, thr = calibrate(cell, engines, names)
-    te = TokenEngine(engines, gear, mode="fused", spec_k=1)
+    # the program's phases and request events, read by per-layer metrics;
+    # the end-to-end runs serve without them
+    telem = None
+    if trace:
+        from repro.core.telemetry import Telemetry
+        telem = Telemetry()
+    te = TokenEngine(engines, gear, mode="fused", spec_k=1, telemetry=telem)
     if fault is not None:
         fault(te)
     arrivals = T.make_requests(mix, seed, seconds)
@@ -373,10 +373,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
 
     stats = jax.devices()[0].memory_stats() or {}
     mem_peak = int(stats.get("peak_bytes_in_use", -1))
-    archs = [costs.arch(cell.config["models"][n]) for n in names]
+    families = [cell.family(n) for n in names]
+    archs = [f.arch(cell.config["models"][n]) for f, n in zip(families,
+                                                              names)]
     records = sorted(loop.records.values(), key=lambda r: r.arrival.rid)
+    if telem is not None:
+        telem.finalize()
     run = RunData(cell, win0, win1, seconds, records, loop.calls, names,
-                  cell.config["n_slots"], archs, {}, t_end=t_end)
+                  cell.config["n_slots"], archs, families, {}, t_end=t_end,
+                  telemetry=telem)
     metrics, breakdown, device_extra = {}, None, {}
     if trace:
         run.peak = peak or peak_of(jax.devices()[0].device_kind)
@@ -412,7 +417,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
         engines = None
     gc.collect()
     t_ref = time.perf_counter()
-    readings = compare(names, {n: a for n, a in zip(names, archs)}, params,
+    readings = compare(names, dict(zip(names, zip(families, archs))),
+                       params,
                        done_by_stage, seed, MIN_COMPARED_TOKENS,
                        MAX_COMPARED_REQUESTS, MIN_COMPARED_REQUESTS,
                        controls)

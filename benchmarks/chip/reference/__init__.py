@@ -1,2 +1,4 @@
-"""Plain float32 references of the served architectures, independent of
-the program under test, and the comparison that decides ``correct``."""
+"""The comparison that decides ``correct`` (``compare.py``), and the float32
+building blocks that the families' plain references share (``ops.py``);
+each reference is its family's (``families/<family>.py``), independent of
+the program under test."""

@@ -11,40 +11,39 @@ tokens taken as given. At each served position two numbers are read:
   that drives escalation, from the top-2-gap kernel) lies from the
   reference's.
 
-Each is the widest over the sample, per stage model. The precision control
-(``quant``) runs the reference in int8 or fp8 in the program's place and
-reads the same two numbers for the tokens and gaps that it puts first.
+Each is the widest over the sample, per stage model. The reference is the
+stage model's family's (``families/<family>.py``: ``hidden``, ``stats``).
+The precision control (``quant``) runs the reference in int8 or fp8 in the
+program's place and reads the same two numbers for the tokens and gaps
+that it puts first.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from costs import Arch
-from reference import forward as F
 
-
-def score(a: Arch, params, prompt: np.ndarray, served: Sequence[int],
+def score(family, a, params, prompt: np.ndarray, served: Sequence[int],
           gaps: Sequence[float], controls: Sequence[str] = ()) -> dict:
     served = np.asarray(served, np.int32)
     gaps = np.asarray(gaps, np.float64)
     p, n = prompt.size, served.size
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     rows = slice(p - 1, p - 1 + n)
-    target = np.full(F.bucket(seq.size), -1, np.int32)
+    x = family.hidden(a, params, seq)
+    target = np.full(x.shape[0], -1, np.int32)
     target[rows] = served
-    x = F.hidden(a, params, seq)
-    short, gap, _ = F.stats(a, params, x, target)
+    short, gap, _ = family.stats(a, params, x, target)
     out = {"tokens": int(n),
            "logit_shortfall": float(short[rows].max()),
            "gap_error": float(np.abs(gap[rows] - gaps).max())}
     for q in controls:
-        xc = F.hidden(a, params, seq, quant=q)
-        _, gap_c, pick = F.stats(a, params, xc, target, quant=q)
+        xc = family.hidden(a, params, seq, quant=q)
+        _, gap_c, pick = family.stats(a, params, xc, target, quant=q)
         target_c = np.full_like(target, -1)
         target_c[rows] = pick[rows]
-        short_c, _, _ = F.stats(a, params, x, target_c)
+        short_c, _, _ = family.stats(a, params, x, target_c)
         out[f"{q}.logit_shortfall"] = float(short_c[rows].max())
         out[f"{q}.gap_error"] = float(np.abs(gap_c[rows] - gap[rows]).max())
     return out
@@ -72,19 +71,22 @@ def sample(done: List[dict], seed: int, min_tokens: int,
     return picked
 
 
-def compare(stages: Sequence[str], archs: Dict[str, Arch], params: list,
+def compare(stages: Sequence[str], models: Dict[str, Tuple[object, object]],
+            params: list,
             done_by_stage: Dict[int, List[dict]], seed: int,
             min_tokens: int, max_requests: int, min_requests: int = 1,
             controls: Sequence[str] = ()) -> Dict[str, dict]:
     """Per stage model: the widest readings over its sample, and the
-    number of requests and tokens compared."""
+    number of requests and tokens compared. ``models`` gives each stage
+    model's (family module, arch)."""
     out = {}
     for si, name in enumerate(stages):
         picked = sample(done_by_stage.get(si, []), seed + si, min_tokens,
                         max_requests, min_requests)
         if not picked:
             continue
-        rows = [score(archs[name], params[si], r["prompt"], r["tokens"],
+        family, a = models[name]
+        rows = [score(family, a, params[si], r["prompt"], r["tokens"],
                       r["gaps"], controls) for r in picked]
         agg = {k: max(r[k] for r in rows) for k in rows[0]
                if k != "tokens"}

@@ -4,17 +4,18 @@ trace.
 ``TokenEngine(telemetry=...)`` times each token boundary's phases
 (``engine.admit``, ``engine.decode`` and their children, on the host
 clock) and annotates each for the profiler with its number ``n``, so a
-trace holds them on the device's clock. This module reads them:
+trace holds them on the device's clock (``tracing.load``'s ``program``).
+This module reads them:
 
-* ``load_program(trace_dir)``: the phases a profiler trace holds;
 * ``reduce_program(events)``: with the device's operations and the
-  driver's host phases (``tracing.load``), each idle gap of the device
-  given to the innermost phase that overlaps it most, counting a phase's
-  own time apart from its children's (to the driver's phase where no
-  phase overlaps it), and the phases the trace holds whole;
-* ``host_gap_ms``, ``prefill_pad_share``, ``escalation_wait_ms``: the
-  numbers the per-layer metrics of the stage engine and the cascade
-  read.
+  driver's host phases, each idle gap of the device given to the
+  innermost phase that overlaps it most, counting a phase's own time apart
+  from its children's (to the driver's phase where no phase overlaps it),
+  the phases the trace holds whole, and each device's idle intervals
+  (``tracing.reduce`` adds these keys to its summary);
+* ``host_gap_ms``, ``prefill_pad_share``,
+  ``escalation_wait_ms``: the numbers the per-layer metrics of the stage
+  engine and the cascade read (``metrics/``).
 
 As a command it serves a cell as ``run.py`` does, with the engine's
 telemetry on, and prints one JSON line: the end-to-end metrics, the
@@ -50,33 +51,6 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parents[1] / "src"))
 
 import tracing  # noqa: E402
-
-PROGRAM_SPANS = ("engine.admit", "engine.decode", "engine.decide",
-                 "slot.prefill", "slot.join", "slot.fetch", "slot.dispatch")
-
-
-def load_program(trace_dir: str) -> List[dict]:
-    """The program's phases in the newest trace under ``trace_dir``, each
-    with its number ``n``, stage and boundary."""
-    import glob
-    import os
-    from jax.profiler import ProfileData
-    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    data = ProfileData.from_file(paths[-1])
-    out = []
-    for plane in data.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name in PROGRAM_SPANS:
-                    out.append({"name": ev.name, "start_ns": ev.start_ns,
-                                "dur_ns": ev.duration_ns,
-                                "n": int(tracing._stat(ev, "n")),
-                                "stage": int(tracing._stat(ev, "s")),
-                                "boundary": int(tracing._stat(ev, "b"))})
-    return sorted(out, key=lambda e: e["n"])
 
 
 def _end(e: dict) -> float:
@@ -131,7 +105,7 @@ def reduce_program(events: dict) -> dict:
     program phase that overlaps it most (``innermost``), else to the
     driver's host phase that does (``tracing.attribute``);
     ``program_calls``: the phases that lie whole inside the traced
-    window."""
+    window; ``idle_gaps``: each device's idle intervals (ns)."""
     program = sorted(events["program"], key=lambda e: e["start_ns"])
     starts = [e["start_ns"] for e in program]
     longest_ns = max((e["dur_ns"] for e in program), default=0)
@@ -150,20 +124,23 @@ def reduce_program(events: dict) -> dict:
                     idle[k] += v
     n_dev = len(by_device)
     return {"idle_by_program": {k: v / n_dev / 1e9 for k, v in idle.items()},
-            "program_calls": program_calls(events)}
+            "program_calls": program_calls(events),
+            "idle_gaps": by_device}
 
 
 def _overlap(intervals: Iterable, a: float, b: float) -> float:
     return sum(max(0.0, min(y, b) - max(x, a)) for x, y in intervals)
 
 
-def host_gap_ms(events: dict, name: str) -> Optional[float]:
+def host_gap_ms(summary: dict, name: str) -> Optional[float]:
     """Mean device idle time (ms, per device) inside one ``name`` phase
-    (its children included), over the phases the trace holds whole."""
-    calls = [e for e in program_calls(events) if e["name"] == name]
+    (its children included), over the phases the trace holds whole, from
+    a reduced trace (``tracing.reduce``'s ``program_calls`` and
+    ``idle_gaps``)."""
+    calls = [e for e in summary["program_calls"] if e["name"] == name]
     if not calls:
         return None
-    by_device = idle_gaps(events)
+    by_device = summary["idle_gaps"]
     idle = sum(_overlap(g, e["start_ns"], _end(e))
                for g in by_device.values() for e in calls)
     return idle / len(by_device) / len(calls) / 1e6
@@ -333,9 +310,9 @@ def serve(cell, seed: int, seconds: float, trace: bool, telemetry: bool,
             tracer.end()
     t_end = time.perf_counter()
     records = sorted(loop.records.values(), key=lambda r: r.arrival.rid)
-    archs = [None] * len(names)
+    unread = [None] * len(names)     # no count is read here
     run = harness.RunData(cell, win0, win1, seconds, records, loop.calls,
-                          names, cell.config["n_slots"], archs, {},
+                          names, cell.config["n_slots"], unread, unread, {},
                           t_end=t_end)
     out = {"device": jax.devices()[0].device_kind,
            "failed": sum(r.done is None for r in window),
@@ -360,18 +337,16 @@ def serve(cell, seed: int, seconds: float, trace: bool, telemetry: bool,
     })
     if tracer is not None:
         events = tracing.load(tracer.path)
-        events["program"] = load_program(tracer.path)
         shutil.rmtree(tracer.path, ignore_errors=True)
         summary = tracing.reduce(events)
-        prog = reduce_program(events)
         out.update({
-            "join_host_gap_ms": host_gap_ms(events, "engine.admit"),
-            "decode_host_gap_ms": host_gap_ms(events, "engine.decode"),
+            "join_host_gap_ms": host_gap_ms(summary, "engine.admit"),
+            "decode_host_gap_ms": host_gap_ms(summary, "engine.decode"),
             "busy_s": summary["busy_s"], "window_s": summary["window_s"],
             "idle_by_host": summary["idle_by_host"],
-            "idle_by_program": prog["idle_by_program"],
+            "idle_by_program": summary["idle_by_program"],
             "program_calls": dict(Counter(
-                e["name"] for e in prog["program_calls"])),
+                e["name"] for e in summary["program_calls"])),
             "profiled_from_s": tracer.t0 - win0,
             "decode_alignment": decode_alignment(events),
         })

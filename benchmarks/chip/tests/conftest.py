@@ -20,6 +20,7 @@ sys.path.insert(0, str(CHIP.parents[1] / "src"))
 def models_entry(cfg) -> dict:
     """A configuration file's ``models`` entry for a registry config."""
     return {
+        "family": "dense_gqa",
         "hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
         "num_attention_heads": cfg.num_heads,
         "num_key_value_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,

@@ -1,5 +1,6 @@
-"""The plain reference against the program's own full forward pass, on
-the same seeded float32 weights, at a tiny size on the CPU."""
+"""The dense family's plain reference against the program's own full
+forward pass, on the same seeded float32 weights, at a tiny size on the
+CPU."""
 import dataclasses
 
 import jax
@@ -7,10 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import costs
 from conftest import models_entry
-from reference import forward as F
+from reference.ops import fake_quant
+from spec import load_family
 from weights import make_params
+
+F = load_family("dense_gqa")
 
 
 @pytest.mark.parametrize("name,seq", [("qwen2-0.5b", 40),
@@ -21,7 +24,7 @@ def test_reference_matches_program_forward(name, seq):
     cfg = dataclasses.replace(get_smoke_config(name), num_layers=2)
     # danube's smoke window (64) is shorter than the sequence: the
     # reference's window mask is exercised
-    a = costs.arch(models_entry(cfg))
+    a = F.arch(models_entry(cfg))
     params = make_params(M.init_params(cfg, spec_only=True,
                                        dtype=jnp.float32), 12345, 0)
     toks = np.random.default_rng(0).integers(0, cfg.vocab_size, seq) \
@@ -43,6 +46,6 @@ def test_int8_and_fp8_controls_round():
     x = jnp.asarray(np.random.default_rng(1).normal(size=(8, 64)),
                     jnp.float32)
     for q, step in (("int8", 1 / 127), ("fp8", 1 / 8)):
-        y = F.fake_quant(x, -1, q)
+        y = fake_quant(x, -1, q)
         rel = jnp.abs(y - x).max() / jnp.abs(x).max()
         assert 0 < float(rel) <= step

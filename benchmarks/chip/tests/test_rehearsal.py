@@ -52,4 +52,8 @@ def test_traced_rehearsal_reports_per_layer(quiet):
     # the host's are read
     assert "queue_wait_p50_ms" in line["metrics"]
     assert "decode_call_ms" in line["metrics"]
+    # the program's telemetry and its phases in the trace reach the readers
+    for name in ("escalation_wait_p50_ms", "prefill_pad_share",
+                 "join_host_gap_ms.chat", "decode_host_gap_ms.chat"):
+        assert name in line["metrics"], name
     assert set(line["metrics"]) <= {m["name"] for m in cell.per_layer}

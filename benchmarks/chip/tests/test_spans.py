@@ -73,12 +73,13 @@ def test_handmade_idle_by_program_by_hand():
 
 def test_handmade_host_gaps_by_hand():
     ev = handmade()
+    s = tracing.reduce(ev)
     # engine.decode 100-9900: idle 100-900 and 7900-9900; the cut decode
     # is not counted
-    assert spans.host_gap_ms(ev, "engine.decode") == pytest.approx(2800e-6)
+    assert spans.host_gap_ms(s, "engine.decode") == pytest.approx(2800e-6)
     # engine.admit 11100-19900: idle 11100-12500, 16000-16500, 17500-19900
-    assert spans.host_gap_ms(ev, "engine.admit") == pytest.approx(4300e-6)
-    assert spans.host_gap_ms(ev, "slot.fetch") == pytest.approx(
+    assert spans.host_gap_ms(s, "engine.admit") == pytest.approx(4300e-6)
+    assert spans.host_gap_ms(s, "slot.fetch") == pytest.approx(
         (100 + 500 + 500) / 2 * 1e-6)
     # the held decode's executable starts 700 ns after its dispatch and
     # ends 100 ns before its fetch
@@ -86,7 +87,7 @@ def test_handmade_host_gaps_by_hand():
     assert a == {"s0": {"start_lag_ms": pytest.approx([7e-4] * 3),
                         "fetch_tail_ms": pytest.approx([1e-4] * 3)}}
     ev["program"] = []
-    assert spans.host_gap_ms(ev, "engine.decode") is None
+    assert spans.host_gap_ms(tracing.reduce(ev), "engine.decode") is None
 
 
 def test_prefill_pad_share_and_escalation_wait_by_hand():
@@ -161,9 +162,10 @@ def test_recorded_slice_readers_by_hand(recorded):
     and in one of 3.028 ms inside the decode, after its executable."""
     from types import SimpleNamespace
     ev = recorded["events"]
-    assert spans.host_gap_ms(ev, "engine.admit") == pytest.approx(
+    s = tracing.reduce(ev)
+    assert spans.host_gap_ms(s, "engine.admit") == pytest.approx(
         27.347, abs=2e-3)
-    assert spans.host_gap_ms(ev, "engine.decode") == pytest.approx(
+    assert spans.host_gap_ms(s, "engine.decode") == pytest.approx(
         3.028, abs=1e-3)
     phases = [SimpleNamespace(**r) for r in recorded["records"]]
     assert spans.prefill_pad_share(phases, 0.0, float("inf")) == \

@@ -1,10 +1,11 @@
 """From a profiler trace to the numbers the per-layer metrics read.
 
 ``load(trace_dir)`` flattens the ``.xplane.pb`` the JAX profiler wrote into
-plain event lists: device operations (with the executable they ran in) and
-the open-loop driver's host phases (its ``TraceAnnotation`` names).
-``reduce`` works on those lists alone, so it is checked on a small
-recorded trace (``tests/data``):
+plain event lists: device operations (with the executable they ran in),
+the open-loop driver's host phases (its ``TraceAnnotation`` names) and the
+program's own phases (``TokenEngine``'s telemetry, each with its number
+``n``, stage and boundary). ``reduce`` works on those lists alone, so it
+is checked on small recorded traces (``tests/data``):
 
 * busy: the union of the intervals in which an operation ran on the device,
   within the traced window (the span of the open-loop driver's host phases);
@@ -15,7 +16,10 @@ recorded trace (``tests/data``):
 * the driver's engine calls (``admit.sN``, ``decode.sN``, each tagged with
   its boundary), whose spans the trace holds whole: the device's events
   that start inside such a span belong to that call, since every call
-  waits for its outputs before it returns.
+  waits for its outputs before it returns;
+* where the events hold the program's phases, ``spans.reduce_program``'s
+  keys: the idle gaps by program phase, the phases held whole, and each
+  device's idle intervals.
 """
 from __future__ import annotations
 
@@ -26,6 +30,8 @@ from typing import Dict, Iterable, List, Sequence, Tuple
 
 HOST_PHASES = ("arrivals", "wait_arrival", "admit.s0", "admit.s1",
                "decode.s0", "decode.s1", "bookkeeping")
+PROGRAM_SPANS = ("engine.admit", "engine.decode", "engine.decide",
+                 "slot.prefill", "slot.join", "slot.fetch", "slot.dispatch")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -38,14 +44,15 @@ def _stat(ev, key):
 
 
 def load(trace_dir: str) -> dict:
-    """{"device": [...], "host": [...]} from the newest trace in the dir."""
+    """{"device": [...], "host": [...], "program": [...]} from the newest
+    trace in the dir."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = ProfileData.from_file(paths[-1])
-    device, host = [], []
+    device, host, program = [], [], []
     for plane in data.planes:
         if plane.name.startswith("/device:"):
             for line in plane.lines:
@@ -64,7 +71,15 @@ def load(trace_dir: str) -> dict:
                                      "start_ns": ev.start_ns,
                                      "dur_ns": ev.duration_ns,
                                      "boundary": _stat(ev, "b")})
-    return {"device": device, "host": host}
+                    elif ev.name in PROGRAM_SPANS:
+                        program.append({
+                            "name": ev.name, "start_ns": ev.start_ns,
+                            "dur_ns": ev.duration_ns,
+                            "n": int(_stat(ev, "n")),
+                            "stage": int(_stat(ev, "s")),
+                            "boundary": int(_stat(ev, "b"))})
+    return {"device": device, "host": host,
+            "program": sorted(program, key=lambda e: e["n"])}
 
 
 def union(intervals: Iterable[Tuple[float, float]]) -> List[Tuple[float,
@@ -215,7 +230,7 @@ def reduce(events: dict) -> dict:
                 by_module[module_of(e["name"])] += (min(b, hi)
                                                     - max(a, lo)) / 1e9
                 n_module[module_of(e["name"])] += 1
-    return {
+    out = {
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy_ns / n_dev / 1e9,
         "by_op": dict(by_op),
@@ -226,6 +241,10 @@ def reduce(events: dict) -> dict:
         "modules": modules,
         "host_calls": [h for h in host if h.get("boundary") is not None],
     }
+    if "program" in events:
+        from spans import reduce_program
+        out.update(reduce_program(events))
+    return out
 
 
 def _inside(e: dict, span) -> bool:

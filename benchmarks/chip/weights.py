@@ -3,8 +3,8 @@ on the device in one jitted call per model, in the dtype each leaf is
 served in.
 
 The benchmark makes the weights, hands them to the system under test, and
-reads the same arrays in the plain reference: the reference interprets the
-leaves by their names (``reference/forward.py``).
+reads the same arrays in the plain reference: the model's family
+(``families/<family>.py``) interprets the leaves by their names.
 """
 from __future__ import annotations
 
