@@ -223,13 +223,38 @@ def prefill_attention(p: Params, cfg: ModelConfig, x: jax.Array,
     return out, cache
 
 
+def write_rows(stack: jax.Array, new: jax.Array, layer: jax.Array,
+               slot: jax.Array) -> jax.Array:
+    """Write each row's new K or V (B,1,KV,hd) into the layer-stacked cache
+    (L,B,C,KV,hd) at ``(layer, row, slot)``, in place; slot is a scalar or
+    ``(B,)``. One ``dynamic_update_slice`` per row, each row reshaped from
+    ``new[i]``, keeps the stack's entry layout: one scatter, or rows sliced
+    from ``new[None]``, made XLA relayout the whole stack around the layer
+    loop (DESIGN.md §14)."""
+    new = new.astype(stack.dtype)
+    if jnp.ndim(slot) == 0:
+        return jax.lax.dynamic_update_slice(stack, new[None],
+                                            (layer, 0, slot, 0, 0))
+    row = (1, 1) + new.shape[1:]
+    for i in range(new.shape[0]):
+        stack = jax.lax.dynamic_update_slice(stack, new[i].reshape(row),
+                                             (layer, i, slot[i], 0, 0))
+    return stack
+
+
 def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
-                     cache: Params, cache_index: jax.Array
+                     cache: Params, cache_index: jax.Array,
+                     layer: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, Params]:
     """One-token decode. x (B,1,D); cache k/v (B,C,KV,hd); cache_index is the
     number of tokens already in context (the new token's position) — a
     scalar, or ``(B,)`` for a ragged batch of requests at different
-    generation depths (continuous batching)."""
+    generation depths (continuous batching).
+
+    With ``layer``, cache k/v are the layer-stacked (L,B,C,KV,hd) caches
+    that the decode scan carries: the new rows go into the stack in place
+    (``write_rows``) and attention reads layer ``layer`` of the result.
+    Returns the output and the updated cache (the stack, with ``layer``)."""
     b = x.shape[0]
     ragged = jnp.ndim(cache_index) != 0
     positions = jnp.broadcast_to(cache_index, (b,)).reshape(b, 1)
@@ -237,19 +262,26 @@ def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
     q = apply_rope(q, positions, cfg.rope_theta)
     k_new = apply_rope(k_new, positions, cfg.rope_theta)
 
-    c_len = cache["k"].shape[1]
+    c_len = cache["k"].shape[-3]
     slot = jnp.mod(cache_index, c_len) if cfg.sliding_window > 0 else cache_index
-    if ragged:
+    if layer is not None:
+        new_cache = {n: write_rows(cache[n], a, layer, slot)
+                     for n, a in (("k", k_new), ("v", v_new))}
+        k, v = (jax.lax.dynamic_index_in_dim(new_cache[n], layer,
+                                             keepdims=False) for n in "kv")
+    elif ragged:
         # per-row scatter: row i writes its own slot[i]
         onehot = jnp.arange(c_len)[None, :] == slot[:, None]      # (B,C)
         sel = onehot[:, :, None, None]
         k = jnp.where(sel, k_new.astype(cache["k"].dtype), cache["k"])
         v = jnp.where(sel, v_new.astype(cache["v"].dtype), cache["v"])
+        new_cache = {"k": k, "v": v}
     else:
         k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot,
                                                 axis=1)
         v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot,
                                                 axis=1)
+        new_cache = {"k": k, "v": v}
 
     idx = jnp.arange(c_len)
     if ragged:
@@ -280,7 +312,7 @@ def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
         # the qwen2 -> danube cascade's device time on a v5e (PERF.md §6).
         out = sdpa_gqa(q, k, v, mask)
     out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim) @ p["wo"]
-    return out, {"k": k, "v": v}
+    return out, new_cache
 
 
 # ---------------------------------------------------------------------------

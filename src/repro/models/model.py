@@ -150,9 +150,10 @@ def _apply_block(spec: LayerSpec, p: Params, cfg: ModelConfig, x: jax.Array,
                  positions: jax.Array, mode: str,
                  cache: Optional[Params], cross_kv: Optional[Params],
                  cache_index: Optional[jax.Array], cache_len: int,
-                 is_causal: bool = True
+                 is_causal: bool = True, layer: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (x, new_cache_or_None, aux_loss)."""
+    """Returns (x, new_cache_or_None, aux_loss). In decode, ``layer`` marks
+    ``cache`` as the layer-stacked attention cache the scan carries."""
     aux = jnp.zeros((), jnp.float32)
     new_cache: Dict[str, jax.Array] = {}
     h = apply_norm(p["norm1"], x, cfg.norm_type, cfg.norm_eps)
@@ -179,7 +180,7 @@ def _apply_block(spec: LayerSpec, p: Params, cfg: ModelConfig, x: jax.Array,
                     p["attn"], cfg, h, cache, cache_index, ctx)
             else:
                 mix, kv = attn.decode_attention(p["attn"], cfg, h, cache,
-                                                cache_index)
+                                                cache_index, layer)
             new_cache.update(kv)
     else:  # ssm mixer
         if mode == "full":
@@ -228,25 +229,42 @@ def _run_blocks(blocks: List[Params], cfg: ModelConfig, x: jax.Array,
     blocks: list (per period position) of rep-stacked param pytrees.
     caches: list (per period position) of rep-stacked cache pytrees (decode).
     cross_kv: list (per position) of rep-stacked {'ck','cv'} (enc-dec decode).
+
+    On one device, decode carries the attention caches through the scan
+    and each layer writes its new rows into the stack in place: as scan
+    ``xs``/``ys`` the stack came back in fresh buffers, copied whole into
+    the donated output (DESIGN.md §14). With a mesh set they stay in
+    ``xs``/``ys``, as does SSM state, which each layer rewrites whole.
     """
     pattern = pattern or block_pattern(cfg)
     reps = jax.tree.leaves(blocks[0])[0].shape[0]
+    kv = None
+    if mode == "decode" and not attn._mesh_set():
+        kv = [c if s.mixer == "attn" else {} for s, c in zip(pattern, caches)]
+        caches = [{} if s.mixer == "attn" else c
+                  for s, c in zip(pattern, caches)]
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux, kv = carry
+        kv = None if kv is None else list(kv)
         block_ps, cache_in, rep_idx = xs
         new_caches = []
         for pos, spec in enumerate(pattern):
             ckv = None
             if spec.cross and cross_kv is not None:
                 ckv = jax.tree.map(lambda a: a[rep_idx], cross_kv[pos])
-            c_in = cache_in[pos] if cache_in is not None else None
+            carried = kv is not None and spec.mixer == "attn"
+            c_in = kv[pos] if carried else (
+                cache_in[pos] if cache_in is not None else None)
             x, c_out, a = _apply_block(
                 spec, block_ps[pos], cfg, x, positions, mode, c_in, ckv,
-                cache_index, cache_len, is_causal)
+                cache_index, cache_len, is_causal,
+                layer=rep_idx if carried else None)
+            if carried:
+                kv[pos], c_out = c_out, None
             aux = aux + a
             new_caches.append(c_out if c_out is not None else {})
-        return (x, aux), new_caches
+        return (x, aux, kv), new_caches
 
     if remat:
         if remat_policy == "dots":
@@ -258,10 +276,12 @@ def _run_blocks(blocks: List[Params], cfg: ModelConfig, x: jax.Array,
             body = jax.checkpoint(body)
 
     xs = (blocks, caches, jnp.arange(reps))
-    (x, aux), caches_out = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                        xs)
+    (x, aux, kv), caches_out = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), kv), xs)
     if mode == "full":
         caches_out = None
+    elif kv is not None:
+        caches_out = [k or c for k, c in zip(kv, caches_out)]
     return x, caches_out, aux
 
 

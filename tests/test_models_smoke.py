@@ -174,3 +174,78 @@ def test_grouped_decode_matches_repeat_form(arch, heads, index):
     np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref32,
                                rtol=0, atol=4 * 2.0 ** -8
                                * float(np.max(np.abs(ref32))))
+
+
+def _ragged_depths(cfg):
+    """Per-row context depths for one ragged decode; row 1 is inactive,
+    pinned to position 0. A ring cache gets a row past its wrap; MoE rows
+    stay under 8 tokens, where capacity routing drops none."""
+    if cfg.sliding_window:
+        return [5, 0, 17, cfg.sliding_window + 6]
+    if cfg.moe is not None:
+        return [5, 0, 2, 7]
+    return [5, 0, 17, 30]
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_ragged_decode_writes_only_its_rows(arch):
+    """One ragged decode step over rows at mixed depths: each row's logits
+    are what it gets decoded alone and what the full forward gives, and
+    the step changes no attention-cache entry but each row's new slot in
+    each layer."""
+    cfg = get_smoke_config(arch)
+    params = M.init_params(cfg, jax.random.PRNGKey(1))
+    depths = _ragged_depths(cfg)
+    b, n_prefix = len(depths), 0
+    if cfg.frontend.kind == "vision":
+        n_prefix = cfg.frontend.num_prefix_embeddings
+    c_len = n_prefix + max(depths) + 2
+    toks = jax.random.randint(jax.random.PRNGKey(2), (b, max(depths) + 1),
+                              0, cfg.vocab_size)
+    lanes, index, want = [], [], []
+    for i, d in enumerate(depths):
+        extra = {k: v[i:i + 1] for k, v in make_batch(
+            cfg, b, 1, with_labels=False, rng=3).items() if k != "tokens"}
+        if d == 0:
+            # an idle lane holds a stale request's cache, and the row
+            # decodes its token with no prefix
+            extra.pop("prefix_embeddings", None)
+            prompt, seen, at = toks[i:i + 1, :9], toks[i:i + 1, :1], 0
+        else:
+            prompt, seen = toks[i:i + 1, :d], toks[i:i + 1, :d + 1]
+            at = d + (n_prefix if "prefix_embeddings" in extra else 0)
+        _, lane = M.prefill(params, cfg, {**extra, "tokens": prompt},
+                            cache_len=c_len)
+        if d == 0:
+            # SSM state is not masked by position: an idle row's is fresh
+            lane["blocks"] = [
+                {n: jnp.zeros_like(a) if n in ("conv", "ssm") else a
+                 for n, a in c.items()} for c in lane["blocks"]]
+        full, _ = M.forward(params, cfg, {**extra, "tokens": seen})
+        lanes.append(lane)
+        index.append(at)
+        want.append(full[0, at])
+    cache = jax.tree.map(lambda *a: jnp.concatenate(a, axis=1), *lanes)
+    index = jnp.asarray(index, jnp.int32)
+    step = toks[jnp.arange(b), jnp.asarray(depths)][:, None]
+    logits, new = M.decode_step(params, cfg, step, cache, index)
+
+    for i in range(b):
+        alone, _ = M.decode_step(params, cfg, step[i:i + 1], lanes[i],
+                                 index[i])
+        np.testing.assert_allclose(np.asarray(logits[i]),
+                                   np.asarray(alone[0]), atol=2e-2, rtol=0)
+        np.testing.assert_allclose(np.asarray(logits[i]),
+                                   np.asarray(want[i]), atol=5e-2, rtol=0)
+
+    for spec, old, upd in zip(M.block_pattern(cfg), cache["blocks"],
+                              new["blocks"]):
+        if spec.mixer != "attn":
+            continue
+        ring = old["k"].shape[2]
+        kept = np.ones((b, ring), bool)
+        kept[np.arange(b), np.asarray(index) % ring] = False
+        for n in ("k", "v"):  # bit for bit
+            np.testing.assert_array_equal(
+                np.asarray(upd[n]).view(np.uint16)[:, kept],
+                np.asarray(old[n]).view(np.uint16)[:, kept])

@@ -5,6 +5,8 @@ qwen2-0.5b widths, with the kernel inside.
 
 Interpret mode, which the kernel tests use, accepts block shapes that the
 chip's compiler refuses; these compiles are what catches that here."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -81,6 +83,27 @@ def test_decode_fused_step_compiles_with_kernel(one_chip, kernel_backend,
     kv = cfg.num_kv_heads
     group = f"[{b},{c_len},{kv},{cfg.num_heads // kv},{cfg.head_dim}]"
     assert group not in hlo
+    # each row's new K/V goes into the donated stack in place: nothing
+    # copies the stack or a layer of it, or selects over either
+    layer = f"[{b},{c_len},{kv},{cfg.head_dim}]"
+    stack = f"[{cfg.num_layers},{b},{c_len},{kv},{cfg.head_dim}]"
+    assert _rewrites(hlo, (layer, stack)) == []
+    assert f"bf16{stack}" in hlo
+
+
+def _rewrites(hlo, shapes):
+    """Instructions whose result has one of ``shapes`` and that copy it or
+    select into it: a ``copy``, a ``select`` or a fusion named for one."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if m is None:
+            continue
+        name, result, op = m.groups()
+        if any(s in result for s in shapes) and (
+                op in ("copy", "select") or "select" in name):
+            found.append(name)
+    return found
 
 
 def test_bucketed_prefill_compiles_with_kernel(one_chip, qwen2_cut,
