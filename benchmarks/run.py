@@ -31,7 +31,6 @@ BENCHES = [
     "bench_replanning",          # beyond-paper online re-planning drift
     "bench_multitenant",         # beyond-paper multi-tenant shared fleet
     "bench_tokens",              # token-level continuous batching vs rebatch
-    "bench_decode_loop",         # device-resident fused loop vs host loop
     "bench_elastic",             # elastic fleet $/M-req over a sim week
     "bench_telemetry",           # span overhead + attribution reconcile
 ]

@@ -24,35 +24,37 @@ point at EVERY token. This module adds the token-native execution layer:
   across architectures; the paper's cascades re-run the larger model from
   scratch for the same reason).
 
-Two execution modes (DESIGN.md §14):
+The decode loop is device-resident (DESIGN.md §14). Greedy argmax, the
+top-2-gap reduction and the streaming-certainty fold run INSIDE the
+jitted step (``models.model.decode_fused_steps``), so each step ships
+``(B,)`` tokens + ``(B,)`` gaps + ``(B,)`` certainty values to the host
+instead of ``(B, V)`` logits, with the KV cache donated back to the
+executable (no double buffering). When nothing is waiting anywhere and
+no row is near a decision boundary, K steps run inside one ``lax.scan``
+per call; the host replays boundary decisions over the returned
+``(K, B)`` traces at the SAME token counts a single-step loop would have
+used (``ContinuousBatcher.stream_trace_hop``), discarding at most K-1
+speculative tokens on an early decision.
 
-* ``fused`` (default) — the device-resident loop. Greedy argmax, the
-  top-2-gap reduction and the streaming-certainty fold run INSIDE the
-  jitted step (``models.model.decode_fused_steps``), so each step ships
-  ``(B,)`` tokens + ``(B,)`` gaps + ``(B,)`` certainty values to the host
-  instead of ``(B, V)`` logits, with the KV cache donated back to the
-  executable (no double buffering). When nothing is waiting anywhere and
-  no row is near a decision boundary, K steps run inside one ``lax.scan``
-  per call; the host replays boundary decisions over the returned
-  ``(K, B)`` traces at the SAME token counts a single-step loop would
-  have used (``ContinuousBatcher.stream_trace_hop``), discarding at most
-  K-1 speculative tokens on an early decision. Joiners prefill in ONE
-  right-padded call per boundary, padded to power-of-two (length, batch)
-  buckets so the compile set is bounded by the bucket grid, not the
-  prompt-length distribution (``models.model.prefill_bucketed``; configs
-  where right padding is not exact — SSM state, MoE capacity routing —
-  fall back to exact-length prefills, see
-  ``bucketed_prefill_supported``).
-* ``reference`` — the PR-7 loop, kept verbatim as the parity baseline:
-  one jit call per decode step returning full logits, per-joiner batch-1
-  prefills, host-side argmax/top-2-gap per row.
+Joiners prefill through one path: one executable call per boundary that
+reduces each row to (first token, gap) on the device
+(``kernels.top2gap.argmax_gap``), one scatter of the new cache rows into
+the pool, one fetch of the ``(n,)`` tokens and gaps. Prompts right-pad to
+power-of-two (length, batch) buckets, so the compile set is bounded by
+the bucket grid, not the prompt-length distribution
+(``models.model.prefill_bucketed``). Where right padding is not exact
+(SSM state, MoE capacity routing, a window ring the pads would alias;
+``bucketed_prefill_supported``), each prompt prefills at its exact
+length instead, one call each, and the rows are batched after.
 
-Decisions are bit-identical across the two modes and the token DES by
-construction: every executor folds the same float64 ``StreamingCertainty``
-over the same per-token gap stream and consults the same
-``ContinuousBatcher`` at the same token counts. Decisions advance in
-deterministic logical steps and read no clock; timing lives in the DES
-(``ServingSimulator.run_token_trace``), which stays the decision oracle.
+Decisions match the token DES by construction: both fold the same
+float64 ``StreamingCertainty`` over the same per-token gap stream and
+consult the same ``ContinuousBatcher`` at the same token counts.
+Decisions advance in deterministic logical steps and read no clock;
+timing lives in the DES (``ServingSimulator.run_token_trace``), which
+stays the decision oracle. ``greedy_generate`` is the plain
+prefill-plus-``decode_step`` reference the tests compare tokens and gaps
+against.
 
 Telemetry (``TokenEngine(telemetry=...)``, DESIGN.md §16) is the one
 place the engine reads a clock, the caller's (``clock``, by default
@@ -61,19 +63,20 @@ phases of each token boundary (``Telemetry.phase``), each also a
 ``jax.profiler.TraceAnnotation``:
 
 * ``engine.admit`` (a boundary's joins at one stage, when the batcher
-  admits any), holding ``slot.prefill`` (the bucketed prefill's dispatch,
-  or the exact-length fallback loop; counts ``rows``, ``batch_bucket``,
-  ``len_bucket`` (0: exact length), ``tokens`` and ``padded``),
-  ``slot.join`` (the cache scatter, then the fused loop's row updates)
-  and ``slot.fetch`` (the host blocked on the first tokens and gaps);
+  admits any), holding ``slot.prefill`` (the prefill's dispatch: one
+  bucketed call, or the exact-length calls; counts ``rows``,
+  ``batch_bucket``, ``len_bucket`` (0: exact length), ``tokens`` and
+  ``padded``), ``slot.join`` (the cache scatter, then the fused loop's
+  row updates) and ``slot.fetch`` (the host blocked on the first tokens
+  and gaps);
 * ``engine.decode`` (one fused decode call; counts ``rows``, ``k``),
   holding ``slot.dispatch`` (the active-mask upload and the executable
   call), ``slot.fetch`` (the host blocked on the traces) and
   ``engine.decide`` (the boundary replay and the leaves; counts
   ``leaves``, ``escalations``).
 
-The reference loop's decode steps are not timed. With ``telemetry=None``
-no annotation is made and the clock is never read.
+With ``telemetry=None`` no annotation is made and the clock is never
+read.
 """
 from __future__ import annotations
 
@@ -93,6 +96,7 @@ from repro.core.certainty import (StreamingCertainty, device_fold_init,
 from repro.core.gears import Gear
 from repro.core.scheduling import (ContinuousBatcher, SchedulerConfig,
                                    SchedulerCore)
+from repro.kernels.top2gap import argmax_gap
 from repro.models import model as model_lib
 
 __all__ = ["SlotEngine", "TokenEngine", "TokenRequest", "TokenResult",
@@ -149,10 +153,10 @@ def _pow2_buckets(lo: int, hi: int) -> List[int]:
 
 @dataclass
 class SlotEngineStats:
-    """Hot-loop instrumentation (bench_decode_loop): executable calls,
-    decode steps executed, and the ANALYTIC host-transfer byte counts of
-    the step outputs/inputs (what crosses the PCIe/ICI boundary per step,
-    not incidental bookkeeping)."""
+    """Hot-loop instrumentation: executable calls, decode steps executed,
+    and the ANALYTIC host-transfer byte counts of the step outputs/inputs
+    (what crosses the PCIe/ICI boundary per step, not incidental
+    bookkeeping)."""
     prefill_calls: int = 0          # prefill executable invocations
     prefill_prompts: int = 0        # prompts prefetched across those calls
     decode_calls: int = 0           # decode executable invocations
@@ -182,23 +186,9 @@ class SlotEngine:
         self.pos = np.zeros(n_slots, np.int32)
         self.active = np.zeros(n_slots, bool)
         self.stats = SlotEngineStats()
-        self._vocab = cfg.vocab_size
         # set by the TokenEngine that drives this pool
         self.telemetry = None
         self.clock: Callable[[], float] = time.perf_counter
-        # --- reference executables (PR-7 loop, parity baseline) ---------
-        # each jitted entry point is a named function: compile logs and
-        # profiler traces report it by that name
-        def reference_decode(p, t, c, i):
-            return model_lib.decode_step(p, cfg, t, c, i)
-
-        def reference_prefill(p, t):
-            return model_lib.prefill(p, cfg, {"tokens": t},
-                                     cache_len=max_len)
-
-        # one decode executable, static shape (n_slots, 1) + (n_slots,)
-        self._decode = jax.jit(reference_decode)
-        self._prefill = jax.jit(reference_prefill)
         # --- fused-loop state (device-resident, DESIGN.md §14) ----------
         self.dev_pos = jnp.zeros((n_slots,), jnp.int32)
         self.dev_tok = jnp.zeros((n_slots,), jnp.int32)
@@ -209,20 +199,25 @@ class SlotEngine:
         self.len_buckets = _pow2_buckets(min(min_len_bucket, max_len),
                                          max_len)
         self.batch_buckets = _pow2_buckets(1, n_slots)
-        if model_lib.bucketed_prefill_supported(cfg):
-            def bucketed_prefill(params, tokens, true_lens):
-                """Batched padded prefill + fused greedy/top-2-gap
-                reduction: the host receives (B,) tokens + (B,) gaps,
-                never (B, V) logits."""
-                from repro.kernels.top2gap import argmax_gap
-                logits, cache = model_lib.prefill_bucketed(
-                    params, cfg, tokens, true_lens, cache_len=max_len)
-                tok, gap = argmax_gap(logits)
-                return tok, gap, cache
+        # both prefills reduce on the device to (B,) tokens + (B,) gaps:
+        # the host never receives (B, V) logits. Each jitted entry point is
+        # a named function: compile logs and profiler traces report it by
+        # that name.
+        def bucketed_prefill(params, tokens, true_lens):
+            logits, cache = model_lib.prefill_bucketed(
+                params, cfg, tokens, true_lens, cache_len=max_len)
+            tok, gap = argmax_gap(logits)
+            return tok, gap, cache
 
-            self._bucketed = jax.jit(bucketed_prefill)
-        else:
-            self._bucketed = None
+        def exact_prefill(params, tokens):
+            logits, cache = model_lib.prefill(
+                params, cfg, {"tokens": tokens}, cache_len=max_len)
+            tok, gap = argmax_gap(logits)
+            return tok, gap, cache
+
+        self._bucketed = jax.jit(bucketed_prefill) \
+            if model_lib.bucketed_prefill_supported(cfg) else None
+        self._exact = jax.jit(exact_prefill)
 
     @property
     def n_active(self) -> int:
@@ -231,13 +226,12 @@ class SlotEngine:
     def compile_counts(self) -> Dict[str, int]:
         """Executable-cache sizes per entry point (compile-stability
         regression hook: the bucketed prefill set must stay bounded by the
-        bucket grid, while the reference prefill compiles one executable
-        per distinct prompt length)."""
+        bucket grid, while the exact-length prefill compiles one
+        executable per distinct prompt length)."""
         out = {
-            "reference_prefill": int(self._prefill._cache_size()),
-            "reference_decode": int(self._decode._cache_size()),
             "bucketed_prefill": int(self._bucketed._cache_size())
             if self._bucketed is not None else 0,
+            "exact_prefill": int(self._exact._cache_size()),
             "fused_decode": sum(int(f._cache_size())
                                 for f in self._fused_fns.values()),
         }
@@ -255,31 +249,6 @@ class SlotEngine:
                 f"prompt ({prompt.size} tokens) leaves no decode headroom "
                 f"in a {self.max_len}-token slot")
         return prompt
-
-    def prefill_into_slot(self, prompt: np.ndarray) -> Tuple[int, np.ndarray]:
-        """Prefill one prompt and scatter its cache into a free slot
-        (reference path). Returns (slot index, last-position logits (V,)).
-        The scatter overwrites the slot's whole cache lane, so stale
-        contents from the previous occupant cannot leak.
-        """
-        if not self.free:
-            raise RuntimeError(f"{self.name}: no free decode slot")
-        prompt = self._check_prompt(prompt)
-        logits, cache1 = self._prefill(self.params, prompt[None, :])
-        slot = self.free.pop()
-        # rep-stacked cache leaves are (reps, B, ...): batch at axis 1
-        self.cache = jax.tree.map(
-            lambda pool, new: pool.at[:, slot].set(
-                new[:, 0].astype(pool.dtype)), self.cache, cache1)
-        self.pos[slot] = prompt.size
-        self.active[slot] = True
-        self._active_dirty = True
-        self.stats.prefill_calls += 1
-        self.stats.prefill_prompts += 1
-        self.stats.prefill_shapes.add((1, int(prompt.size)))
-        self.stats.bytes_to_device += prompt.size * 4
-        self.stats.bytes_to_host += self._vocab * 4
-        return slot, np.asarray(logits[0])
 
     def _len_bucket(self, n: int) -> int:
         for b in self.len_buckets:
@@ -309,15 +278,19 @@ class SlotEngine:
 
     def prefill_batch(self, prompts: Sequence[np.ndarray]
                       ) -> Tuple[List[int], np.ndarray, np.ndarray]:
-        """Prefill all of a boundary's joiners (fused path).
+        """Prefill all of a boundary's joiners and scatter their caches
+        into free slots.
 
-        One right-padded call per boundary when the config supports exact
-        padded prefill: prompts pad to the smallest power-of-two length
-        bucket covering the longest joiner, the batch pads to a batch
-        bucket, and the executable returns per-row (first token, gap) —
-        so the compile set is (len buckets x batch buckets), invariant to
-        the prompt-length distribution. Unsupported configs (SSM / MoE /
-        enc-dec) keep exact-length per-prompt prefills.
+        Where the config's padded prefill is exact, one right-padded call
+        per boundary: prompts pad to the smallest power-of-two length
+        bucket covering the longest joiner and the batch pads to a batch
+        bucket, so the compile set is (len buckets x batch buckets),
+        invariant to the prompt-length distribution. Elsewhere each prompt
+        prefills at its exact length, one call each, and the rows are
+        batched after. Either executable returns per-row (first token,
+        gap); the scatter, the fetch and the row updates are shared. The
+        scatter overwrites each slot's whole cache lane, so stale contents
+        from the previous occupant cannot leak.
 
         Returns (slots, first tokens (n,), first gaps (n,)).
         """
@@ -328,42 +301,41 @@ class SlotEngine:
         if n > len(self.free):
             raise RuntimeError(
                 f"{self.name}: {n} joiners for {len(self.free)} free slots")
-        lb = self._len_bucket(max(p.size for p in prompts))
+        plens = np.asarray([p.size for p in prompts], np.int32)
+        real = int(plens.sum())
+        lb = self._len_bucket(int(plens.max()))
         telem, clock = self.telemetry, self.clock
-        if self._bucketed is None or (
-                self.cfg.sliding_window > 0
-                and lb >= min(self.cfg.sliding_window, self.max_len)):
-            # exact-length fallback: pads are not semantically invisible
-            # here (SSM state / MoE routing / window ring aliasing)
-            slots, toks, gaps = [], [], []
-            with _phase(telem, clock, "slot.prefill") as ph:
-                for p in prompts:
-                    slot, logits = self.prefill_into_slot(p)
-                    slots.append(slot)
-                    toks.append(int(np.argmax(logits)))
-                    gaps.append(
-                        float(np.asarray(top2_gap(logits[None, :]))[0]))
-                if ph is not None:
-                    real = sum(p.size for p in prompts)
-                    ph.counts.update(rows=n, batch_bucket=1, len_bucket=0,
-                                     tokens=real, padded=real)
-            toks = np.asarray(toks, np.int32)
-            gaps = np.asarray(gaps, np.float32)
-            with _phase(telem, clock, "slot.join"):
-                self._join_rows(slots, np.asarray([p.size for p in prompts]),
-                                toks, gaps)
-            return slots, toks, gaps
         with _phase(telem, clock, "slot.prefill") as ph:
-            bb = self._batch_bucket(n)
-            arr = np.zeros((bb, lb), np.int32)
-            lens = np.ones((bb,), np.int32)
-            for i, p in enumerate(prompts):
-                arr[i, :p.size] = p
-                lens[i] = p.size
-            tok_d, gap_d, cache1 = self._bucketed(self.params, arr, lens)
+            if self._bucketed is None or (
+                    self.cfg.sliding_window > 0
+                    and lb >= min(self.cfg.sliding_window, self.max_len)):
+                # exact length: pads are not semantically invisible here
+                # (SSM state / MoE routing / window ring aliasing)
+                outs = [self._exact(self.params, p[None, :])
+                        for p in prompts]
+                tok_d = jnp.concatenate([o[0] for o in outs])
+                gap_d = jnp.concatenate([o[1] for o in outs])
+                # rep-stacked cache leaves are (reps, B, ...): batch at 1
+                cache1 = jax.tree.map(
+                    lambda *c: jnp.concatenate(c, axis=1),
+                    *(o[2] for o in outs))
+                calls, bb, lb, padded = n, 1, 0, real
+                shapes = {(1, int(p.size)) for p in prompts}
+                sent = int(plens.nbytes)
+            else:
+                bb = self._batch_bucket(n)
+                arr = np.zeros((bb, lb), np.int32)
+                lens = np.ones((bb,), np.int32)
+                for i, p in enumerate(prompts):
+                    arr[i, :p.size] = p
+                    lens[i] = p.size
+                tok_d, gap_d, cache1 = self._bucketed(self.params, arr, lens)
+                calls, padded = 1, bb * lb
+                shapes = {(bb, lb)}
+                sent = arr.nbytes + lens.nbytes
             if ph is not None:
                 ph.counts.update(rows=n, batch_bucket=bb, len_bucket=lb,
-                                 tokens=int(lens[:n].sum()), padded=bb * lb)
+                                 tokens=real, padded=padded)
         slots = [self.free.pop() for _ in range(n)]
         with _phase(telem, clock, "slot.join"):
             rows = jnp.asarray(np.asarray(slots, np.int32))
@@ -373,16 +345,15 @@ class SlotEngine:
         with _phase(telem, clock, "slot.fetch"):
             toks = np.asarray(tok_d[:n])
             gaps = np.asarray(gap_d[:n])
-        plens = lens[:n]
         for slot, plen in zip(slots, plens):
             self.pos[slot] = plen
             self.active[slot] = True
         with _phase(telem, clock, "slot.join"):
             self._join_rows(slots, plens, toks, gaps)
-        self.stats.prefill_calls += 1
+        self.stats.prefill_calls += calls
         self.stats.prefill_prompts += n
-        self.stats.prefill_shapes.add((bb, lb))
-        self.stats.bytes_to_device += arr.nbytes + lens.nbytes
+        self.stats.prefill_shapes |= shapes
+        self.stats.bytes_to_device += sent
         self.stats.bytes_to_host += n * 8          # (tok, gap) per joiner
         return slots, toks, gaps
 
@@ -397,36 +368,6 @@ class SlotEngine:
         self._active_dirty = True
 
     # ------------------------------------------------------ decode steps
-
-    def decode(self, tokens_by_slot: Dict[int, int]) -> Dict[int, np.ndarray]:
-        """One ragged decode step over the resident batch (reference
-        path: full logits come back to the host).
-
-        tokens_by_slot: {slot: next input token} for every ACTIVE slot.
-        Idle slots ride along at position 0 with a zero token (their rows
-        are independent under the per-row ragged masks and their lanes are
-        fully overwritten at the next prefill scatter). Returns
-        {slot: logits (V,)} and advances each active slot's depth.
-        """
-        if set(tokens_by_slot) != set(np.flatnonzero(self.active)):
-            raise ValueError("decode needs exactly the active slots")
-        slots = np.fromiter(tokens_by_slot.keys(), np.int64,
-                            len(tokens_by_slot))
-        vals = np.fromiter(tokens_by_slot.values(), np.int64, len(slots))
-        if (self.pos[slots] >= self.max_len).any():
-            full = int(slots[np.argmax(self.pos[slots] >= self.max_len)])
-            raise ValueError(f"slot {full} is full ({self.max_len} tokens)")
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        toks[slots, 0] = vals
-        logits, self.cache = self._decode(
-            self.params, toks, self.cache, self.pos)
-        logits = np.asarray(logits)
-        self.pos[slots] += 1
-        self.stats.decode_calls += 1
-        self.stats.decode_steps += 1
-        self.stats.bytes_to_device += self.n_slots * 8   # tokens + pos
-        self.stats.bytes_to_host += self.n_slots * self._vocab * 4
-        return {int(s): logits[s] for s in slots}
 
     def _get_fused(self, mode: str, beta: float):
         key = (mode, float(beta))
@@ -532,9 +473,8 @@ class TokenEngine:
     the whole request set to completion in deterministic logical steps —
     one step = (admit + prefill joiners) then one decode phase per stage.
 
-    ``mode='fused'`` (default) drives the device-resident loop;
-    ``mode='reference'`` is the PR-7 host loop, kept as the bit-parity
-    baseline. ``spec_k`` > 1 enables speculative multi-token scans: K
+    Each stage decodes on the device-resident loop (module docstring).
+    ``spec_k`` > 1 enables speculative multi-token scans: K
     decode steps per executable call whenever no request is waiting at ANY
     stage (so admission decisions cannot shift — the K-collapse rule) and
     no resident row is near a decision boundary
@@ -566,12 +506,12 @@ class TokenEngine:
             raise ValueError(
                 f"stage engines {[e.name for e in stages]} do not match "
                 f"the gear cascade {list(gear.cascade.models)}")
-        if mode not in ("fused", "reference"):
-            raise ValueError(f"mode must be fused|reference, got {mode!r}")
+        # ``mode`` names the one loop; it stays only because existing
+        # callers pass mode="fused"
+        if mode != "fused":
+            raise ValueError(f"mode must be 'fused', got {mode!r}")
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
-        if mode == "reference" and spec_k != 1:
-            raise ValueError("speculative scans need mode='fused'")
         self.stages = list(stages)
         self.gear = gear
         self.core = SchedulerCore([], cfg)
@@ -580,7 +520,6 @@ class TokenEngine:
                               early_margin=early_margin) for e in stages]
         self.stream_mode = stream_mode
         self.beta = beta
-        self.mode = mode
         self.spec_k = spec_k
         self.k_guard_slack = k_guard_slack
         self.spec_discarded = 0       # speculative tokens thrown away
@@ -614,11 +553,7 @@ class TokenEngine:
             for si, eng in enumerate(self.stages):
                 # admission at the token boundary: prefill phase first
                 self._admit(si, eng, waiting, act, step, custody=False)
-                if not act[si]:
-                    continue
-                if self.mode == "reference":
-                    self._step_reference(si, eng, waiting, act, step)
-                else:
+                if act[si]:
                     self._step_fused(si, eng, waiting, act, step)
             step += 1
         return results
@@ -646,20 +581,9 @@ class TokenEngine:
 
     def _join(self, si: int, eng: SlotEngine, pairs, act, step: int
               ) -> None:
-        if self.mode == "reference":
-            joined = []
-            for req, res in pairs:
-                slot, logits = eng.prefill_into_slot(req.prompt)
-                gap = float(np.asarray(top2_gap(logits[None, :]))[0])
-                tok = int(np.argmax(logits))
-                joined.append((req, res, slot, tok, gap))
-        else:
-            slots, toks, gaps = eng.prefill_batch(
-                [req.prompt for req, _ in pairs])
-            joined = [(req, res, slot, int(tok), float(gap))
-                      for (req, res), slot, tok, gap
-                      in zip(pairs, slots, toks, gaps)]
-        for req, res, slot, tok, gap in joined:
+        slots, toks, gaps = eng.prefill_batch([req.prompt for req, _ in pairs])
+        for (req, res), slot, tok, gap in zip(pairs, slots, toks.tolist(),
+                                              gaps.tolist()):
             cert = StreamingCertainty(mode=self.stream_mode, beta=self.beta)
             cert.update(gap)
             res.tokens.append(tok)
@@ -697,26 +621,6 @@ class TokenEngine:
         if self.telemetry is not None:
             self.telemetry.registry.gauge(
                 "kv_slots_active", model=eng.name).set(eng.n_active)
-
-    def _step_reference(self, si: int, eng: SlotEngine, waiting, act,
-                        step: int) -> None:
-        """PR-7 loop: one host round-trip of (B, V) logits per step."""
-        out = eng.decode({a.slot: a.next_token for a in act[si]})
-        for a in act[si]:
-            logits = out[a.slot]
-            gap = float(np.asarray(top2_gap(logits[None, :]))[0])
-            a.cert.update(gap)
-            a.next_token = int(np.argmax(logits))
-            a.res.tokens.append(a.next_token)
-            a.res.gaps.append(gap)
-        # token-boundary decisions (iterate over a copy: leaves mutate
-        # the active list)
-        for a in list(act[si]):
-            hop = self.batchers[si].boundary_hop(
-                si, a.cert.value, len(a.res.tokens), a.req.max_new,
-                self.gear)
-            if hop is not None:
-                self._leave(si, eng, a, hop, waiting, act, step)
 
     def _choose_k(self, si: int, eng: SlotEngine, waiting, act) -> int:
         """The K-collapse rule. K > 1 only when (a) NOTHING is waiting at

@@ -1,8 +1,10 @@
-"""Device-resident decode loop (DESIGN.md §14): fused-step decision parity
-vs the reference host loop, speculative multi-token scans (K-collapse rule,
-trace replay, discard), bucketed batched prefill exactness + gating, the
-device-side certainty fold vs the host fold, engine-vs-token-DES decision
-parity through recorded gap streams, and compile-count stability."""
+"""Device-resident decode loop (DESIGN.md §14): fused-loop tokens and gaps
+vs the plain greedy reference with decisions vs the token DES, speculative
+multi-token scans (K-collapse rule, trace replay, discard), bucketed batched
+prefill exactness + gating, the exact-length join on SSM and windowed
+stages, the device-side certainty fold vs the host fold, engine-vs-token-DES
+decision parity through recorded gap streams, and compile-count
+stability."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,33 +38,59 @@ def _requests(cfg, n, rng, base=8, max_new=6):
                          max_new) for i in range(n)]
 
 
+# The logits leave a bf16 LM head, and |logit| < 1 in these smoke models.
+# The engine decodes rows in a batch, greedy_generate one row alone: the
+# two round a logit to the same or a neighbouring bf16 value (2**-8 apart
+# below 1), so a top-2 gap agrees to two such steps.
+GAP_ATOL = 2.0 ** -7
+
+
 def _gear1():
     return Gear(cascade=Cascade(("m",), ()), min_queue_lens={"m": 1},
                 load_fractions={"m": {0: 1.0}})
 
 
+def _assert_des_decisions(gear, reqs, out, n_slots):
+    """Replaying the engine's recorded gap streams through the token DES
+    gives each request the same resolver; its hops and its visited stages
+    follow from that resolver along the linear cascade."""
+    names = list(gear.cascade.models)
+    backend = TokenReplayBackend.from_gap_streams(
+        names, [out[r.rid].stage_gaps for r in reqs],
+        [r.max_new for r in reqs])
+    sim = ServingSimulator(
+        synthetic_family(names, seed=0),
+        [Replica(m, i, 1e-3 * (i + 1)) for i, m in enumerate(names)],
+        len(names), SimConfig(max_batch=8))
+    res = sim.run_token_trace(
+        gear, np.zeros(len(reqs)), [r.prompt.size for r in reqs],
+        backend, mode="continuous", n_slots=n_slots, min_tokens=2)
+    assert res.completed == len(reqs)
+    for r, resolver in zip(reqs, res.resolver):
+        o = out[r.rid]
+        assert o.resolver == resolver and o.hops == resolver
+        assert sorted(o.stage_gaps) == list(range(resolver + 1))
+
+
 # ---------------------------------------------------------------------------
-# Fused loop vs reference loop: bit-identical decisions at K=1
+# Fused loop vs the plain greedy reference, decisions vs the token DES
 # ---------------------------------------------------------------------------
 
 def test_fused_matches_reference_bit_identical():
-    """The device-resident loop must be invisible: same tokens, same
-    decisions, same logical timings as the PR-7 host loop at K=1."""
+    """The device-resident loop must be invisible: each request gets the
+    tokens of a plain prefill + decode_step run, and the DES decides
+    alike from its recorded gaps."""
     cfg, params = _setup("qwen2-0.5b", seed=0)
     rng = np.random.default_rng(0)
     reqs = _requests(cfg, 5, rng)
-    outs = {}
-    for mode in ("fused", "reference"):
-        eng = SlotEngine("m", params, cfg, n_slots=3, max_len=40)
-        te = TokenEngine([eng], _gear1(), min_tokens=2, mode=mode)
-        outs[mode] = te.serve(reqs)
+    eng = SlotEngine("m", params, cfg, n_slots=3, max_len=40)
+    out = TokenEngine([eng], _gear1(), min_tokens=2).serve(reqs)
     for r in reqs:
-        f, g = outs["fused"][r.rid], outs["reference"][r.rid]
-        assert f.tokens == g.tokens
-        assert f.resolver == g.resolver and f.hops == g.hops
-        assert f.first_token_step == g.first_token_step
-        assert f.done_step == g.done_step
-        np.testing.assert_allclose(f.gaps, g.gaps, atol=1e-4, rtol=0)
+        solo, sgaps = greedy_generate(params, cfg, r.prompt, r.max_new)
+        assert out[r.rid].tokens == solo.tolist()
+        np.testing.assert_allclose(out[r.rid].gaps, sgaps, atol=GAP_ATOL,
+                                   rtol=0)
+    _assert_des_decisions(_gear1(), reqs, out, n_slots=3)
 
 
 def test_fused_escalation_matches_reference():
@@ -73,20 +101,22 @@ def test_fused_escalation_matches_reference():
                 min_queue_lens={"a": 1, "b": 1},
                 load_fractions={"a": {0: 1.0}, "b": {1: 1.0}})
     reqs = _requests(cfg, 3, rng, max_new=6)
-    outs = {}
-    for mode in ("fused", "reference"):
-        stages = [SlotEngine("a", params_a, cfg, n_slots=2, max_len=40),
-                  SlotEngine("b", params_b, cfg, n_slots=2, max_len=40)]
-        te = TokenEngine(stages, gear, min_tokens=2, mode=mode)
-        outs[mode] = te.serve(reqs)
-    for rid in outs["fused"]:
-        f, g = outs["fused"][rid], outs["reference"][rid]
-        assert f.tokens == g.tokens and f.resolver == g.resolver == 1
-        assert f.hops == g.hops >= 1
-        assert sorted(f.stage_gaps) == sorted(g.stage_gaps)
-        for si in f.stage_gaps:
-            np.testing.assert_allclose(f.stage_gaps[si], g.stage_gaps[si],
-                                       atol=1e-4, rtol=0)
+    stages = [SlotEngine("a", params_a, cfg, n_slots=2, max_len=40),
+              SlotEngine("b", params_b, cfg, n_slots=2, max_len=40)]
+    out = TokenEngine(stages, gear, min_tokens=2).serve(reqs)
+    for r in reqs:
+        o = out[r.rid]
+        assert o.resolver == 1 and o.hops >= 1
+        # the abandoned stage-0 stream is a prefix of stage 0's own run
+        _, gaps_a = greedy_generate(params_a, cfg, r.prompt, r.max_new)
+        np.testing.assert_allclose(o.stage_gaps[0],
+                                   gaps_a[:len(o.stage_gaps[0])],
+                                   atol=GAP_ATOL, rtol=0)
+        solo, gaps_b = greedy_generate(params_b, cfg, r.prompt, r.max_new)
+        assert o.tokens == solo.tolist()
+        np.testing.assert_allclose(o.stage_gaps[1], gaps_b,
+                                   atol=GAP_ATOL, rtol=0)
+    _assert_des_decisions(gear, reqs, out, n_slots=2)
 
 
 # ---------------------------------------------------------------------------
@@ -154,10 +184,9 @@ def test_near_boundary_guard_and_validation():
     assert not cb.near_boundary(1, 0.0, 5, 10, gear)   # terminal stage
     cfg, params = _setup("qwen2-0.5b", seed=0)
     eng = SlotEngine("m", params, cfg, n_slots=2, max_len=16)
-    with pytest.raises(ValueError):
-        TokenEngine([eng], _gear1(), mode="reference", spec_k=2)
-    with pytest.raises(ValueError):
-        TokenEngine([eng], _gear1(), mode="turbo")
+    for mode in ("reference", "turbo"):    # one loop: only "fused"
+        with pytest.raises(ValueError):
+            TokenEngine([eng], _gear1(), mode=mode)
     with pytest.raises(ValueError):
         TokenEngine([eng], _gear1(), spec_k=0)
     with pytest.raises(RuntimeError):
@@ -253,6 +282,30 @@ def test_fused_engine_on_ssm_falls_back_to_exact_prefill():
     assert all(b == 1 for b, _ in eng.stats.prefill_shapes)
 
 
+def test_fused_engine_on_window_falls_back_to_exact_prefill():
+    """A windowed stage whose length bucket reaches the window joins
+    through the exact-length prefill (pads would alias live ring slots)
+    and still serves each request the plain greedy tokens."""
+    cfg, params = _setup("h2o-danube-1.8b", seed=2)
+    assert cfg.sliding_window == 64
+    rng = np.random.default_rng(9)
+    eng = SlotEngine("m", params, cfg, n_slots=2, max_len=128)
+    te = TokenEngine([eng], _gear1(), min_tokens=2)
+    # two at a time through two slots: each pair's longest prompt has a
+    # length bucket of 64 or 128, so the 20-token one joins exactly too
+    lens = (40, 63, 70, 20)
+    reqs = [TokenRequest(i, rng.integers(0, cfg.vocab_size,
+                                         n).astype(np.int32), 6)
+            for i, n in enumerate(lens)]
+    out = te.serve(reqs)
+    for r in reqs:
+        solo, _ = greedy_generate(params, cfg, r.prompt, r.max_new)
+        assert out[r.rid].tokens == solo.tolist()
+    assert eng.stats.prefill_shapes == {(1, n) for n in lens}
+    cc = eng.compile_counts()
+    assert cc["exact_prefill"] == len(lens) and cc["bucketed_prefill"] == 0
+
+
 # ---------------------------------------------------------------------------
 # Engine vs token DES: decision parity through recorded gap streams
 # ---------------------------------------------------------------------------
@@ -320,8 +373,8 @@ def test_from_gap_streams_validation():
 
 def test_compile_counts_bounded_by_bucket_grid():
     """The fused engine's executable count is bounded by the bucket grid
-    regardless of the prompt-length distribution; the reference engine
-    compiles one prefill per DISTINCT length."""
+    regardless of the prompt-length distribution; the exact-length
+    fallback (an SSM stage) compiles one prefill per DISTINCT length."""
     cfg, params = _setup("qwen2-0.5b", seed=0)
     rng = np.random.default_rng(7)
     lens = [5, 6, 7, 9, 11, 13, 17, 19]          # 8 distinct lengths
@@ -336,31 +389,29 @@ def test_compile_counts_bounded_by_bucket_grid():
     assert cc["bucketed_prefill"] == len(eng.stats.prefill_shapes) <= grid
     assert cc["bucketed_prefill"] < len(set(lens))   # beats per-length
     assert cc["fused_decode"] == 1                   # K=1 only
-    assert cc["reference_prefill"] == cc["reference_decode"] == 0
-    # the reference engine's compile count tracks the length distribution
-    ref = SlotEngine("m", params, cfg, n_slots=4, max_len=40)
-    tr = TokenEngine([ref], _gear1(), min_tokens=2, mode="reference")
-    tr.serve(reqs)
-    assert ref.compile_counts()["reference_prefill"] == len(set(lens))
+    assert cc["exact_prefill"] == 0
+    # the exact-length fallback's compile count tracks the distribution
+    mcfg, mparams = _setup("falcon-mamba-7b", seed=0)
+    ssm = SlotEngine("m", mparams, mcfg, n_slots=4, max_len=40)
+    TokenEngine([ssm], _gear1(), min_tokens=2).serve(reqs)
+    sc = ssm.compile_counts()
+    assert sc["exact_prefill"] == len(set(lens))
+    assert sc["bucketed_prefill"] == 0
 
 
 def test_fused_step_transfer_is_o_b():
-    """Per decode step the fused loop ships O(B) scalars, the reference
-    loop O(B·V) logits — the tentpole's transfer claim, measured."""
+    """Per decode step the fused loop ships O(B) scalars where a host
+    loop would ship the (B, V) f32 logits — the transfer claim, counted."""
     cfg, params = _setup("qwen2-0.5b", seed=0)
     rng = np.random.default_rng(8)
     reqs = _requests(cfg, 3, rng, max_new=5)
-    per_step = {}
-    for mode in ("fused", "reference"):
-        eng = SlotEngine("m", params, cfg, n_slots=3, max_len=40)
-        te = TokenEngine([eng], _gear1(), min_tokens=2, mode=mode)
-        te.serve(reqs)
-        # prefill transfers excluded: count decode-step output bytes only
-        n_steps = eng.stats.decode_steps
-        if mode == "fused":
-            per_step[mode] = 12 * eng.n_slots
-            assert eng.stats.bytes_to_host >= n_steps * per_step[mode]
-        else:
-            per_step[mode] = 4 * eng.n_slots * cfg.vocab_size
-    assert per_step["reference"] / per_step["fused"] == \
-        pytest.approx(cfg.vocab_size / 3.0)
+    eng = SlotEngine("m", params, cfg, n_slots=3, max_len=40)
+    TokenEngine([eng], _gear1(), min_tokens=2).serve(reqs)
+    fused = 12 * eng.n_slots                 # (B,) token + gap + cert
+    logits = 4 * eng.n_slots * cfg.vocab_size
+    # every byte to the host: the decode steps' traces plus one
+    # (token, gap) pair per prefilled prompt
+    assert eng.stats.decode_steps > 0
+    assert eng.stats.bytes_to_host == \
+        eng.stats.decode_steps * fused + 8 * eng.stats.prefill_prompts
+    assert logits / fused == pytest.approx(cfg.vocab_size / 3.0)

@@ -107,10 +107,10 @@ def test_slot_engine_validation():
     cfg, params = _setup("qwen2-0.5b", seed=0)
     eng = SlotEngine("m", params, cfg, n_slots=1, max_len=16)
     with pytest.raises(ValueError):
-        eng.prefill_into_slot(np.arange(16, dtype=np.int32))  # no headroom
-    slot, _ = eng.prefill_into_slot(np.arange(4, dtype=np.int32))
+        eng.prefill_batch([np.arange(16, dtype=np.int32)])    # no headroom
+    (slot,), _, _ = eng.prefill_batch([np.arange(4, dtype=np.int32)])
     with pytest.raises(RuntimeError):
-        eng.prefill_into_slot(np.arange(4, dtype=np.int32))   # pool full
+        eng.prefill_batch([np.arange(4, dtype=np.int32)])     # pool full
     eng.release(slot)
     with pytest.raises(ValueError):
         eng.release(slot)                                     # double free
